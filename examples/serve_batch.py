@@ -19,6 +19,7 @@ from repro.core.dag import Task, Workflow
 from repro.core.payloads import fn_payload
 from repro.core.runner import run_experiment
 from repro.models import RunConfig, build
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main():
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = build(cfg, RunConfig())

@@ -39,6 +39,7 @@ from repro.core.volumes import VolumeManager
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.optim.adamw import OptConfig, init_state
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.train import TrainRunConfig, build_train_step
 
 
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--layers", type=int, default=0)
     ap.add_argument("--inject-failure", action="store_true", default=True)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     if args.d_model:

@@ -22,7 +22,8 @@ def stress_payload(volume, task):
 
 
 def matmul_payload(n: int = 256, iters: int = 4) -> Callable:
-    """A real CPU-bound JAX payload (used in payload_mode='real')."""
+    """A real device payload (used in payload_mode='real'): ``iters``
+    jitted (n, n) f32 matmul+tanh steps on the default JAX device."""
     import jax
     import jax.numpy as jnp
 
@@ -47,7 +48,10 @@ def matmul_payload(n: int = 256, iters: int = 4) -> Callable:
 
 
 def fn_payload(fn: Callable[[], Optional[dict]]) -> Callable:
-    """Wrap an arbitrary thunk (e.g. a jitted train step) as a payload."""
+    """Wrap an arbitrary thunk (e.g. a jitted train step) as a payload.
+
+    The thunk's result is returned, so that ``sim.measure_wall`` can wait
+    for any device arrays in it before it stops the pod's clock."""
 
     def run(volume, task):
         result = fn()
@@ -55,5 +59,6 @@ def fn_payload(fn: Callable[[], Optional[dict]]) -> Callable:
             for dep in task.inputs:
                 _ = volume.get(f"{dep}/out")
             volume.put(f"{task.id}/out", result if result is not None else True)
+        return result
 
     return run

@@ -639,6 +639,11 @@ class ShardedControlPlane:
                              f"'raise', 'restart', or 'degrade'")
         if wal_dir is not None and gateway is None:
             raise ValueError("wal_dir requires a gateway policy")
+        if payload_mode == "real" and processes:
+            raise ValueError(
+                "payload_mode='real' needs processes=False: real payloads "
+                "run on the accelerator, which one process holds at a time, "
+                "so forked shard workers would fail or hang waiting for it")
         self.workers = workers
         self.processes = processes
         self.shard_procs = shard_procs

@@ -45,7 +45,7 @@ import heapq
 import itertools
 import os
 import time
-from typing import Callable, List, Optional, Tuple
+from typing import Any, Callable, List, Optional, Tuple
 
 
 class Event:
@@ -279,7 +279,14 @@ class Sim:
         return self._live == 0
 
 
-def measure_wall(fn: Callable[[], None]) -> float:
+def measure_wall(fn: Callable[[], Any]) -> float:
+    """Wall seconds of ``fn()``, including the device work it enqueued:
+    a non-None result is blocked on (``jax.block_until_ready``) before
+    the clock stops. jax is imported only then, so virtual payloads
+    (which return None) keep the control plane jax-free."""
     t0 = time.perf_counter()
-    fn()
+    out = fn()
+    if out is not None:
+        import jax
+        jax.block_until_ready(out)
     return time.perf_counter() - t0

@@ -14,7 +14,7 @@ packing). Causally-dead kv tiles are skipped with pl.when (the §Perf
 block-skipping the pure-jnp path lacks).
 
 Validated on CPU with interpret=True against kernels/ref.attention_ref
-(see tests/test_kernels.py); on TPU the same call compiles natively.
+(see tests/test_kernels.py); compiled for v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -71,11 +71,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool = False):
     """q,k,v: (B, S, H, hd) full-H form -> (B, S, H, hd).
 
-    interpret=True runs the kernel body in Python on CPU (the validation
-    mode for this container); pass interpret=False on real TPU.
+    interpret=True runs the kernel body in Python on the CPU (the
+    validation mode of the tests).
     """
     Bz, S, H, hd = q.shape
     T = k.shape[1]

@@ -1,15 +1,13 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-Backends:
+Backends (callers name one; nothing is picked behind their back):
   'pallas'     — native TPU lowering (production target)
   'interpret'  — Pallas interpret mode (kernel body on CPU; validation)
   'jnp'        — the pure-jnp production paths (models/attention,
                  models/ssm), used by the distributed dry-run
-  'auto'       — pallas on TPU, jnp elsewhere
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import flash_attention as fa
@@ -18,21 +16,13 @@ from repro.models import attention as attn_lib
 from repro.models import ssm as ssm_lib
 
 
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
-
-
-def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
+def attention(q, k, v, *, impl: str, causal: bool = True,
               block_q: int = 128, block_k: int = 128, chunk: int = 1024):
     """Full-H attention (B,S,H,hd)x3 -> (B,S,H,hd)."""
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "jnp"
-    if impl == "pallas":
+    if impl in ("pallas", "interpret"):
         return fa.flash_attention(q, k, v, causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=False)
-    if impl == "interpret":
-        return fa.flash_attention(q, k, v, causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=True)
+                                  block_k=block_k,
+                                  interpret=impl == "interpret")
     if impl == "jnp":
         if q.shape[1] > chunk:
             return attn_lib.chunked_attention(q, k, v, chunk=chunk,
@@ -41,19 +31,12 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
     raise ValueError(impl)
 
 
-def ssd(x, dt, A, B, C, *, chunk: int = 128, impl: str = "auto"):
+def ssd(x, dt, A, B, C, *, impl: str, chunk: int = 128):
     """Chunked SSD scan -> (y, final_state)."""
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "jnp"
-    if impl == "pallas":
-        return ssd_mod.ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=False)
-    if impl == "interpret":
-        return ssd_scan_interpret(x, dt, A, B, C, chunk=chunk)
+    if impl in ("pallas", "interpret"):
+        return ssd_mod.ssd_scan(x, dt, A, B, C, chunk=chunk,
+                                interpret=impl == "interpret")
     if impl == "jnp":
         dtf = jnp.asarray(dt, jnp.float32)
         return ssm_lib.ssd_chunked(x, dtf, A, B, C, chunk)
     raise ValueError(impl)
-
-
-def ssd_scan_interpret(x, dt, A, B, C, *, chunk: int = 128):
-    return ssd_mod.ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=True)

@@ -19,7 +19,8 @@ class RunConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     remat: bool = False                # activation checkpointing over blocks
-    remat_policy: str = "none"        # none | dots | everything
+    remat_policy: str = "none"        # dots = save matmul outputs;
+                                       # anything else = full remat
     attn_chunk: int = 0                # >0: online-softmax chunked attention block
     attn_dense_max: int = 8192         # use dense attention up to this seq_len
     attn_shard: str = "heads"          # 'heads' | 'seq' (q-sequence TP when
@@ -33,7 +34,6 @@ class RunConfig:
                                        # stash at the cost of AG/RS pairs
     moe_group: int = 2048              # MoE dispatch group size (tokens)
     ssd_chunk: int = 0                 # SSD chunk override (0 = ArchConfig's)
-    use_pallas: bool = False           # TPU kernels (interpret-validated on CPU)
     # logical-axis -> PartitionSpec constrain hook, injected by the runtime.
     # Signature: constrain(x, logical_axes: tuple) -> x.  Default: identity.
     constrain: Callable = field(default=lambda x, axes: x)

@@ -107,3 +107,20 @@ def test_ops_dispatch():
     o_int = ops.attention(q, k, v, impl="interpret", block_q=32, block_k=32)
     np.testing.assert_allclose(np.asarray(o_jnp), np.asarray(o_int),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_ops_ssd_dispatch():
+    from repro.kernels import ops
+    ks = jax.random.split(jax.random.PRNGKey(2), 5)
+    b, s, h, p, n = 1, 64, 2, 8, 16
+    x = jax.random.normal(ks[0], (b, s, h, p), jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
+    A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
+    B = jax.random.normal(ks[3], (b, s, n)) * 0.5
+    C = jax.random.normal(ks[4], (b, s, n)) * 0.5
+    y_jnp, st_jnp = ops.ssd(x, dt, A, B, C, chunk=32, impl="jnp")
+    y_int, st_int = ops.ssd(x, dt, A, B, C, chunk=32, impl="interpret")
+    np.testing.assert_allclose(np.asarray(y_jnp), np.asarray(y_int),
+                               atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(st_jnp), np.asarray(st_int),
+                               atol=2e-4, rtol=2e-4)
