@@ -107,6 +107,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.core import calibration as cal
+from repro.core import tracing
 from repro.core.shuffle import ExactShuffler
 from repro.core.sim import Sim, measure_wall
 from repro.core.stats import StreamingStat
@@ -831,7 +832,9 @@ class Cluster:
         self._notify("pod", MODIFIED, pod)
         dur = pod.duration_s
         if pod.payload is not None and self.payload_mode == "real":
-            dur = measure_wall(pod.payload)
+            with tracing.span("pod.payload", namespace=pod.namespace,
+                              task=pod.task_id):
+                dur = measure_wall(pod.payload)
         elif pod.payload is not None:
             pod.payload()                            # run, but virtual timing
         dur *= self.nodes[pod.node].slow_factor

@@ -11,6 +11,8 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro.core import tracing
+
 
 def stress_payload(volume, task):
     """task-emulator analogue: consume inputs, emit a completion marker."""
@@ -35,14 +37,17 @@ def matmul_payload(n: int = 256, iters: int = 4) -> Callable:
         return out
 
     def run(volume, task):
-        x = jnp.asarray(np.random.default_rng(0).standard_normal((n, n)),
-                        jnp.float32)
-        y = body(x)
-        y.block_until_ready()
-        if volume is not None:
-            for dep in task.inputs:
-                _ = volume.get(f"{dep}/out")
-            volume.put(f"{task.id}/out", np.asarray(y[0, :4]))
+        with tracing.span("payload.input"):
+            x = jnp.asarray(np.random.default_rng(0).standard_normal((n, n)),
+                            jnp.float32)
+        with tracing.span("payload.compute"):
+            y = body(x)
+            y.block_until_ready()
+        with tracing.span("payload.output"):
+            if volume is not None:
+                for dep in task.inputs:
+                    _ = volume.get(f"{dep}/out")
+                volume.put(f"{task.id}/out", np.asarray(y[0, :4]))
 
     return run
 

@@ -47,6 +47,8 @@ import os
 import time
 from typing import Any, Callable, List, Optional, Tuple
 
+from repro.core import tracing
+
 
 class Event:
     """One scheduled callback: ``fn(*args)`` at a point in virtual time."""
@@ -248,30 +250,35 @@ class Sim:
         wall0 = time.perf_counter()
         cpu0 = time.process_time()
         pop = self._q.pop_due
-        while self._live > 0:
-            item = pop(until)
-            if item is None:
-                break
-            t, _, ev = item
-            self.t = self.last_event_t = t
-            if not ev.daemon:
-                self._live -= 1
-            ev.fn(*ev.args)
-            n += 1
-            if n >= max_events:
-                self.events_processed += n
-                notes = self._q.head_notes(8)
-                raise RuntimeError(
-                    f"sim exceeded {max_events} events — likely a polling "
-                    f"loop never terminated; next pending notes: "
-                    f"{notes if notes else '(unnamed events)'}")
-        self.events_processed += n
-        # wall time of event processing only — ends with the last
-        # processed event (the clock's last_event_t), so throughput
-        # figures exclude setup before the loop and any epilogue after
-        # it (benchmarks divide events by this, see bench_scale)
-        self.run_wall_s += time.perf_counter() - wall0
-        self.run_cpu_s += time.process_time() - cpu0
+        try:
+            with tracing.span("sim.run"):
+                while self._live > 0:
+                    item = pop(until)
+                    if item is None:
+                        break
+                    t, _, ev = item
+                    self.t = self.last_event_t = t
+                    if not ev.daemon:
+                        self._live -= 1
+                    ev.fn(*ev.args)
+                    n += 1
+                    if n >= max_events:
+                        notes = self._q.head_notes(8)
+                        raise RuntimeError(
+                            f"sim exceeded {max_events} events — likely a "
+                            f"polling loop never terminated; next pending "
+                            f"notes: {notes if notes else '(unnamed events)'}")
+        finally:
+            # counted however the loop ends, an exception unwinding it
+            # included (a payload's error, a benchmark window closing).
+            # Wall time of event processing only — ends with the last
+            # processed event (the clock's last_event_t), so throughput
+            # figures exclude setup before the loop and any epilogue
+            # after it (benchmarks divide events by this, see
+            # bench_scale)
+            self.events_processed += n
+            self.run_wall_s += time.perf_counter() - wall0
+            self.run_cpu_s += time.process_time() - cpu0
         if until is not None and until > self.t:
             self.t = until
 

@@ -148,6 +148,29 @@ def test_sim_run_parks_clock_at_horizon_on_drain():
     assert sim3.t == 2.0
 
 
+def test_sim_run_counts_events_before_a_payload_error():
+    """An exception out of an event (a payload's error, a benchmark
+    window closing) unwinds run(); its counters still cover the events
+    processed before the raise."""
+    sim = Sim()
+    ran = []
+
+    def payload(i):
+        if i == 3:
+            raise RuntimeError("payload failed")
+        ran.append(i)
+
+    for i in range(6):
+        sim.at(float(i), payload, args=(i,))
+    with pytest.raises(RuntimeError, match="payload failed"):
+        sim.run()
+    assert ran == [0, 1, 2]
+    assert sim.events_processed == 3
+    assert sim.run_wall_s > 0.0 and sim.run_cpu_s >= 0.0
+    sim.run()                                  # resumes after the raise
+    assert ran == [0, 1, 2, 4, 5] and sim.events_processed == 5
+
+
 # ---------------------------------------------------------------------------
 # cross-layer equivalence: fast vs chained lifecycle, calendar vs heap
 # ---------------------------------------------------------------------------
