@@ -25,7 +25,11 @@ def stress_payload(volume, task):
 
 def matmul_payload(n: int = 256, iters: int = 4) -> Callable:
     """A real device payload (used in payload_mode='real'): ``iters``
-    jitted (n, n) f32 matmul+tanh steps on the default JAX device."""
+    jitted (n, n) f32 matmul+tanh steps on the default JAX device.
+
+    The input depends on ``n`` alone, so the closure builds it once, on
+    its first call, and keeps it resident on the device for every pod
+    after that."""
     import jax
     import jax.numpy as jnp
 
@@ -36,10 +40,18 @@ def matmul_payload(n: int = 256, iters: int = 4) -> Callable:
         out, _ = jax.lax.scan(step, x, None, length=iters)
         return out
 
+    operand = None
+
     def run(volume, task):
+        nonlocal operand
         with tracing.span("payload.input"):
-            x = jnp.asarray(np.random.default_rng(0).standard_normal((n, n)),
-                            jnp.float32)
+            if operand is None:
+                with tracing.span("payload.build"):
+                    operand = jnp.asarray(
+                        np.random.default_rng(0).standard_normal((n, n)),
+                        jnp.float32)
+            # every pod passes the same array: body donates no argument
+            x = operand
         with tracing.span("payload.compute"):
             y = body(x)
             y.block_until_ready()

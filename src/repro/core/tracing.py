@@ -18,7 +18,10 @@ The spans, from the outside in:
     sim.run          the control plane's event loop (``Sim.run``)
     pod.payload      one pod's payload, as the paper times it
                      (``Cluster._start_one``), args namespace and task
-    payload.input    host input preparation and upload  (matmul_payload)
+    payload.input    resident operand, built on the first call (matmul_payload)
+    payload.build    that build, inside payload.input: numpy draw and
+                     upload, once per closure and process (its count is
+                     how often the resident operand misses)
     payload.compute  dispatch and device wait           (matmul_payload)
     payload.output   read-back and the shared-volume hand-off
     gc               one garbage collection, arg generation

@@ -2,9 +2,11 @@
 and no gc hook with the profiler off; under a profiler session, one
 ``pod.payload`` and one of each ``payload.*`` span per pod, self times
 that leave out the child spans, garbage collections as ``gc`` spans, and
-totals that cover one session."""
+totals that cover one session. ``matmul_payload`` builds its operand
+once per closure (one ``payload.build`` span) and keeps it resident."""
 import gc
 import glob
+import inspect
 import json
 import os
 import subprocess
@@ -12,6 +14,8 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs.workflows import get_workflow_spec
@@ -25,12 +29,13 @@ ROOT = Path(__file__).resolve().parent.parent
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+from bench.ref.matmul import payload_input  # noqa: E402
 from bench.trace import SPAN_PRIORITY  # noqa: E402
 PAYLOAD_SPANS = ("payload.input", "payload.compute", "payload.output")
 
 
-def real_plane(wf):
-    payload = matmul_payload(n=64, iters=2)
+def real_plane(wf, payload=None):
+    payload = payload or matmul_payload(n=64, iters=2)
     for t in wf.tasks.values():
         t.payload = payload
     plane = ControlPlane("kubeadaptor", payload_mode="real")
@@ -47,9 +52,9 @@ def pair():
                              "b": Task(id="b", inputs=["a"])})
 
 
-def run_plane(wf) -> int:
+def run_plane(wf, payload=None) -> int:
     """Runs one workflow with real payloads; returns the pods run."""
-    plane = real_plane(wf)
+    plane = real_plane(wf, payload)
     plane.run()
     pods = plane.cluster.pod_log
     assert pods and all(p.phase == SUCCEEDED for p in pods)
@@ -127,7 +132,8 @@ def test_spans_land_in_the_trace_host_plane(traced):
 
 def test_no_span_name_is_a_benchmark_span(traced):
     _, snap, _ = traced
-    assert set(snap) == {"sim.run", "pod.payload", "gc", *PAYLOAD_SPANS}
+    assert set(snap) == {"sim.run", "pod.payload", "gc", "payload.build",
+                         *PAYLOAD_SPANS}
     assert not set(snap) & set(SPAN_PRIORITY)
 
 
@@ -143,6 +149,51 @@ def test_totals_cover_the_latest_session(tmp_path):
     gc.collect()                     # the hook finds the profiler off
     assert tracing._gc_hook not in gc.callbacks
     assert tracing.snapshot() == snap
+
+
+def test_operand_is_built_once_per_closure(tmp_path):
+    payload = matmul_payload(n=64, iters=2)
+    with jax.profiler.trace(str(tmp_path / "first")):
+        n = run_plane(montage(), payload)
+    snap = tracing.snapshot()
+    assert n > 1
+    assert snap["payload.build"]["count"] == 1
+    assert snap["payload.input"]["count"] == n
+    build, inp = snap["payload.build"], snap["payload.input"]
+    assert build["total_s"] <= inp["total_s"] - inp["self_s"] + 1e-9
+    with jax.profiler.trace(str(tmp_path / "again")):
+        n = run_plane(montage(), payload)
+    snap = tracing.snapshot()
+    assert "payload.build" not in snap
+    assert snap["payload.input"]["count"] == n
+
+
+def _captured(payload, outputs):
+    """``payload``, keeping each pod's output as it writes it."""
+    def run(volume, task):
+        payload(volume, task)
+        outputs.append(volume.get(f"{task.id}/out"))
+    return run
+
+
+def test_outputs_match_a_fresh_operand():
+    payload = matmul_payload(n=64, iters=2)
+    outputs = []
+    n = run_plane(montage(), _captured(payload, outputs))
+    body = inspect.getclosurevars(payload).nonlocals["body"]
+    want = np.asarray(body(jnp.asarray(payload_input(64)))[0, :4])
+    assert len(outputs) == n > 1
+    for got in outputs:
+        assert np.array_equal(got, want)
+
+
+def test_resident_operand_survives_the_pods():
+    payload = matmul_payload(n=64, iters=2)
+    assert run_plane(montage(), payload) > 1
+    run_plane(pair(), payload)
+    operand = inspect.getclosurevars(payload).nonlocals["operand"]
+    assert not operand.is_deleted()
+    assert np.array_equal(np.asarray(operand), payload_input(64))
 
 
 def test_virtual_run_keeps_jax_out():
