@@ -1,6 +1,7 @@
 """Architecture registry: ``--arch <id>`` resolution.
 
-All ten assigned architectures plus the paper's own workload (the
+The ten assigned architectures, deepseek-v2-lite (the benchmark's
+latent-attention MoE configuration), plus the paper's own workload (the
 KubeAdaptor paper has no model of its own — its workloads are workflow
 DAGs, registered in ``configs/workflows.py``).
 """
@@ -17,6 +18,7 @@ from repro.configs import (
     qwen2_0p5b,
     musicgen_medium,
     llama32_vision_11b,
+    deepseek_v2_lite,
 )
 
 _MODULES = (
@@ -30,6 +32,7 @@ _MODULES = (
     qwen2_0p5b,
     musicgen_medium,
     llama32_vision_11b,
+    deepseek_v2_lite,
 )
 
 REGISTRY = {m.CONFIG.name: m.CONFIG for m in _MODULES}

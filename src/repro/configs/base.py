@@ -17,6 +17,22 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 configures it:
+    the rotary frequencies between the ``beta_fast`` and ``beta_slow``
+    rotation counts of the original context are ramped from
+    extrapolation to interpolation by ``factor``, and attention scores
+    are scaled by ``get_mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
@@ -50,6 +66,22 @@ class ArchConfig:
     n_shared_experts: int = 0
     shared_expert_d_ff: int = 0
     capacity_factor: float = 1.25
+    moe_dropless: bool = False       # True: every routed slot is computed
+                                     # (grouped product); False: GShard capacity
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
+    moe_aux: str = "switch"          # switch | seq (DeepSeek sequence-level)
+    aux_loss_alpha: float = 0.01     # weight of the summed aux losses
+    n_experts_held: int = 0          # experts this chip holds (0 = all)
+    expert_offset: int = 0           # id of the first expert held
+    first_k_dense: int = 0           # leading dense layers (width d_ff)
+
+    # latent attention (MLA, DeepSeek-V2; kv_lora_rank 0 = plain attention)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnScaling] = None
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0               # N (d_state); 0 = no SSM layers
@@ -97,6 +129,15 @@ class ArchConfig:
         return _round_up(self.n_experts, 16)
 
     @property
+    def n_experts_local(self) -> int:
+        """Experts whose weights this chip stores."""
+        return self.n_experts_held or self.n_experts_padded
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def ssm_d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -121,12 +162,19 @@ class ArchConfig:
         n += self.vocab_padded * d * (1 if self.tie_embeddings else 2)
         per_layer = 0
         if self.family in ("dense", "moe", "audio", "vlm") or self.attn_every:
-            attn = d * (self.n_heads * hd) * 2 + d * (self.n_kv_heads * hd) * 2
+            if self.is_mla:
+                H, r = self.n_heads, self.kv_lora_rank
+                dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                              self.v_head_dim)
+                attn = (d * H * (dn + dr) + d * (r + dr) + r
+                        + r * H * (dn + dv) + H * dv * d)
+            else:
+                attn = d * (self.n_heads * hd) * 2 + d * (self.n_kv_heads * hd) * 2
             if self.qkv_bias:
                 attn += (self.n_heads + 2 * self.n_kv_heads) * hd
             mlp = 0
             if self.n_experts:
-                mlp += self.n_experts * 3 * d * self.expert_d_ff
+                mlp += self._n_experts_stored * 3 * d * self.expert_d_ff
                 mlp += d * self.n_experts  # router
                 if self.shared_expert_d_ff:
                     mlp += 3 * d * self.shared_expert_d_ff
@@ -153,6 +201,8 @@ class ArchConfig:
         else:
             per_layer = block
             n += self.n_layers * per_layer
+            if self.first_k_dense:     # leading dense layers: MLP, no experts
+                n += self.first_k_dense * (3 * d * self.d_ff - mlp)
         if self.family == "vlm" and self.cross_attn_every:
             n_cross = self.n_layers // self.cross_attn_every
             cross = d * (self.n_heads * hd) * 2 + d * (self.n_kv_heads * hd) * 2 + 2 * d
@@ -164,9 +214,14 @@ class ArchConfig:
         if not self.n_experts:
             return self.param_count()
         d = self.d_model
-        dense_experts = self.n_experts * 3 * d * self.expert_d_ff
+        dense_experts = self._n_experts_stored * 3 * d * self.expert_d_ff
         active_experts = self.top_k * 3 * d * self.expert_d_ff
-        return self.param_count() - self.n_layers * (dense_experts - active_experts)
+        n_moe = self.n_layers - self.first_k_dense
+        return self.param_count() - n_moe * (dense_experts - active_experts)
+
+    @property
+    def _n_experts_stored(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     # ---- reduced config for CPU smoke tests --------------------------
     def reduced(self) -> "ArchConfig":
@@ -181,10 +236,22 @@ class ArchConfig:
             n_heads=4 if self.n_heads else 0,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads else 0,
         )
-        if self.n_experts:
+        if self.n_experts and self.moe_dropless:
+            # keeps the published routing (top-k of many, shared experts)
+            changes.update(n_experts=16, top_k=min(self.top_k, 6),
+                           expert_d_ff=64, n_experts_held=0, expert_offset=0,
+                           n_shared_experts=min(self.n_shared_experts, 2),
+                           shared_expert_d_ff=128 if self.shared_expert_d_ff else 0)
+        elif self.n_experts:
             changes.update(n_experts=8, top_k=min(self.top_k, 2), expert_d_ff=64,
                            n_shared_experts=min(self.n_shared_experts, 1),
                            shared_expert_d_ff=64 if self.shared_expert_d_ff else 0)
+        if self.is_mla:
+            changes.update(kv_lora_rank=32, qk_nope_head_dim=32,
+                           qk_rope_head_dim=16, v_head_dim=32, n_kv_heads=4,
+                           head_dim=48)
+        if self.first_k_dense:
+            changes.update(first_k_dense=1, n_layers=3)
         if self.ssm_state:
             changes.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=16)
         if self.attn_every:

@@ -26,6 +26,22 @@ The spans, from the outside in:
     payload.output   read-back and the shared-volume hand-off
     gc               one garbage collection, arg generation
 
+Counters ride on the same sessions: ``tracing.count("moe.slots_held",
+n)`` adds ``n`` (an int, or a device scalar, read only while a session
+is on) to that name's total, and ``snapshot()`` gives a counter's
+count of calls, ``total`` and ``max``. With the profiler off a call
+costs one check and reads nothing. The benchmark's MoE pipeline counts,
+after each stage's wait:
+
+    moe.slots_held       slots routed to the experts held here, per
+                         train step, summed over MoE layers
+    moe.slots_dropped    of those, the slots not computed (train and
+                         decode steps); 0 on the dropless path
+    moe.load_max         each MoE layer's largest held-expert load, per
+                         train step, summed over layers
+    moe.experts_touched  held experts given any slot, per decode step,
+                         summed over layers
+
 ``gc`` comes from a ``gc.callbacks`` hook that the first span of a
 session installs and that goes once the profiler is found off, by the
 hook itself or by the next span site: untraced runs run no Python
@@ -54,6 +70,7 @@ _state = None            # jax's profiler state: its session object
 
 _lock = threading.RLock()      # a gc span may close inside _add
 _totals: Dict[str, List[float]] = {}   # name -> [count, total, self, max]
+_counts: Dict[str, List[int]] = {}     # counter name -> [calls, total, max]
 _session = None          # the profiler session the totals cover
 _live = False            # a session is being recorded
 _hooked = False          # _gc_hook is in gc.callbacks
@@ -104,13 +121,36 @@ def span(name: str, **args):
     return _Span(name, args)
 
 
-def snapshot() -> Dict[str, Dict[str, float]]:
-    """The latest profiler session's totals: name -> count, total_s,
-    self_s and max_s. Empty before any span was recorded."""
+def count(name: str, n) -> None:
+    """Add ``n`` to the counter ``name``; a no-op unless profiling, and
+    ``n`` (a device scalar, say) is read only then."""
+    if not _on():
+        if _hooked:
+            _end()
+        return
+    n = int(n)
     with _lock:
-        return {name: {"count": int(c), "total_s": t, "self_s": s,
-                       "max_s": m}
-                for name, (c, t, s, m) in _totals.items()}
+        if not _live or _session_key() is not _session:
+            _begin()
+        rec = _counts.get(name)
+        if rec is None:
+            rec = _counts[name] = [0, 0, n]
+        rec[0] += 1
+        rec[1] += n
+        rec[2] = max(rec[2], n)
+
+
+def snapshot() -> Dict[str, Dict[str, float]]:
+    """The latest profiler session's totals: a span's name -> count,
+    total_s, self_s and max_s; a counter's -> count (calls), total and
+    max. Empty before any span or count was recorded."""
+    with _lock:
+        out = {name: {"count": int(c), "total_s": t, "self_s": s,
+                      "max_s": m}
+               for name, (c, t, s, m) in _totals.items()}
+        out.update({name: {"count": c, "total": t, "max": m}
+                    for name, (c, t, m) in _counts.items()})
+        return out
 
 
 class _Span:
@@ -146,14 +186,15 @@ def _session_key():
 
 
 def _begin() -> None:
-    """At the first span of a profiler session: fresh totals and the gc
-    hook."""
-    global _totals, _session, _live, _hooked
+    """At the first span or count of a profiler session: fresh totals
+    and the gc hook."""
+    global _totals, _counts, _session, _live, _hooked
     with _lock:
         key = _session_key()
         if _live and key is _session:        # another thread began it
             return
         _totals = {}
+        _counts = {}
         _session = key
         _live = True
         if not _hooked:

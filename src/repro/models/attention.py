@@ -12,13 +12,16 @@ row-parallel all-reduce at wo/w2. Decode keeps the (K, G) folded form:
 the KV cache stays in K heads (the big tensor) and the tiny score psum
 is cheaper than materializing a repeated cache.
 
-The chunked path is the memory-subquadratic attention used for 32k
-prefill: O(S * chunk) live scores instead of O(S^2). The Pallas flash
+The chunked path is the memory-subquadratic attention used for long
+prefill and training (32k prefill, DeepSeek-V2's 8k train step):
+causal query blocks against the key chunks up to their own, one block's
+O(chunk^2) scores live at a time instead of O(S^2). The Pallas flash
 kernel (kernels/flash_attention.py) implements the same algorithm for
 TPU; ``kernels/ops.py`` dispatches between them.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -58,9 +61,11 @@ def repeat_kv(k, n_heads: int):
     return k.reshape(B, T, K * G, hd)
 
 
-def full_attention(q, k, v, *, causal: bool, q_offset: int = 0):
-    """Dense attention in full-H form. q:(B,S,H,hd) k/v:(B,T,H,hd)."""
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
+def full_attention(q, k, v, *, causal: bool, q_offset: int = 0, scale=None):
+    """Dense attention in full-H form. q:(B,S,H,dq) k:(B,T,H,dq)
+    v:(B,T,H,dv); ``scale`` defaults to dq ** -0.5."""
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
     scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
     if causal:
         S, T = scores.shape[-2], scores.shape[-1]
@@ -71,44 +76,83 @@ def full_attention(q, k, v, *, causal: bool, q_offset: int = 0):
     return jnp.einsum("bhst,bthd->bshd", p, v)
 
 
-def chunked_attention(q, k, v, *, chunk: int, causal: bool = True):
-    """Online-softmax attention, scanning KV in blocks of ``chunk``.
+def _key_chunk(carry, xs, *, q, scale, q_pos, causal):
+    """One chunk of keys into the online softmax of the head-major query
+    rows q (B,H,s,dq) at positions ``q_pos``: carry (max, sum, acc)."""
+    m, l, acc = carry
+    kj, vj, k_pos = xs
+    s = jnp.einsum("bhsd,bhtd->bhst", q, kj,
+                   preferred_element_type=jnp.float32) * scale
+    if causal:
+        s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
+    m_new = jnp.maximum(m, s.max(-1))
+    corr = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new[..., None])
+    acc = acc * corr[..., None] + jnp.einsum(
+        "bhst,bhtd->bhsd", p.astype(vj.dtype), vj,
+        preferred_element_type=jnp.float32)
+    return (m_new, l * corr + p.sum(-1), acc), None
 
-    Full-H form. Memory: O(S * chunk) scores live at once (vs O(S^2)
-    dense). FLOPs are the full S^2 (future blocks are masked, not
-    skipped) — block skipping is a recorded §Perf hillclimb item.
-    """
-    B, S, H, hd = q.shape
+
+def _query_block(q, kc, vc, k_pos, *, scale, q_pos, causal):
+    """Head-major rows q (B,H,s,dq) against the key chunks kc, vc
+    (n, B,H,chunk, .) at positions k_pos (n, chunk), online."""
+    B, H, S = q.shape[:3]
+    init = (jnp.full((B, H, S), NEG_INF, jnp.float32),
+            jnp.zeros((B, H, S), jnp.float32),
+            jnp.zeros((B, H, S, vc.shape[-1]), jnp.float32))
+    # each chunk is rematerialised: the backward pass keeps only the
+    # running max, sum and output of every chunk, never its scores
+    (_, l, acc), _ = jax.lax.scan(
+        jax.checkpoint(partial(_key_chunk, q=q, scale=scale, q_pos=q_pos,
+                               causal=causal)), init, (kc, vc, k_pos))
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(vc.dtype)
+
+
+def chunked_attention(q, k, v, *, chunk: int, causal: bool = True,
+                      scale=None):
+    """Online-softmax attention over key chunks of ``chunk`` keys, in
+    full-H form: q (B,S,H,dq), k (B,T,H,dq), v (B,T,H,dv); ``scale``
+    defaults to dq ** -0.5.
+
+    Causal with S == T a multiple of ``chunk``: query blocks of
+    ``chunk`` rows, block i scanning key chunks 0 .. i only (keys past
+    the block are never scored, so the FLOPs are about half of S^2),
+    one block after the other and each rematerialised, so one block's
+    scores live at a time: O(chunk^2) of them. Otherwise every query row
+    scans every chunk (masked if causal), O(S * chunk) scores live. No
+    softmax row spans more than one chunk (on the TPU a softmax's row
+    reductions over many thousand keys compile to a cost quadratic in
+    the row)."""
+    B, S, H, _ = q.shape
     T = k.shape[1]
-    n_blocks = T // chunk
-    assert n_blocks * chunk == T, (T, chunk)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    kb = k.reshape(B, n_blocks, chunk, H, hd)
-    vb = v.reshape(B, n_blocks, chunk, H, hd)
-    qpos = jnp.arange(S)
-
-    def step(carry, xs):
-        m, l, acc = carry
-        kj, vj, j = xs
-        s = jnp.einsum("bshd,bchd->bhsc", q, kj).astype(jnp.float32) * scale
-        if causal:
-            kpos = j * chunk + jnp.arange(chunk)
-            s = jnp.where(qpos[:, None] >= kpos[None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l = l * corr + p.sum(axis=-1)
-        pv = jnp.einsum("bhsc,bchd->bshd", p.astype(vj.dtype), vj)
-        acc = acc * corr.transpose(0, 2, 1)[..., None].astype(acc.dtype) + pv
-        return (m_new, l, acc), None
-
-    m0 = jnp.full((B, H, S), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, H, S), jnp.float32)
-    a0 = jnp.zeros((B, S, H, hd), v.dtype)
-    (m, l, acc), _ = jax.lax.scan(
-        step, (m0, l0, a0), (kb.swapaxes(0, 1), vb.swapaxes(0, 1), jnp.arange(n_blocks)))
-    l = jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
-    return (acc.astype(jnp.float32) / l).astype(v.dtype)
+    n = T // chunk
+    assert n * chunk == T, (T, chunk)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
+    # head-major: each chunk's products batch over heads
+    q = q.transpose(0, 2, 1, 3)
+    kc = k.reshape(B, n, chunk, H, -1).transpose(1, 0, 3, 2, 4)
+    vc = v.reshape(B, n, chunk, H, -1).transpose(1, 0, 3, 2, 4)
+    k_pos = jnp.arange(T).reshape(n, chunk)
+    if not causal or S != T:
+        out = _query_block(q, kc, vc, k_pos, scale=scale,
+                           q_pos=jnp.arange(S), causal=causal)
+        return out.transpose(0, 2, 1, 3)
+    outs = []
+    for i in range(n):
+        q_i = q[:, :, i * chunk:(i + 1) * chunk]
+        if outs:
+            # block i starts once block i - 1 is done, and in the backward
+            # pass block i - 1 once block i is (its key and value
+            # gradients added on as each block finishes)
+            outs[-1], q_i, kc, vc = jax.lax.optimization_barrier(
+                (outs[-1], q_i, kc, vc))
+        fn = jax.checkpoint(partial(
+            _query_block, scale=scale,
+            q_pos=i * chunk + jnp.arange(chunk), causal=True))
+        outs.append(fn(q_i, kc[:i + 1], vc[:i + 1], k_pos[:i + 1]))
+    return jnp.concatenate(outs, axis=2).transpose(0, 2, 1, 3)
 
 
 def _gqa_fold(q, n_kv):

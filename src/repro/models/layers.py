@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Tuple
 
@@ -115,13 +116,45 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** exponent)          # (head_dim//2,)
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, n_heads, head_dim); positions: broadcastable to (..., S)."""
+def yarn_get_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor (DeepSeek-V2's yarn_get_mscale)."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, ys) -> jax.Array:
+    """YaRN inverse frequencies (``ys`` a configs.base.YarnScaling): each
+    frequency is ramped from its extrapolated value (rotation counts
+    above ``beta_fast`` in the original context) to its value
+    interpolated by ``factor`` (counts below ``beta_slow``)."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(ys.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(ys.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(ys.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                        # 1 = extrapolate
+    return extra / ys.factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope(x, positions, theta: float, freqs=None, mscale: float = 1.0):
+    """x: (..., S, n_heads, head_dim); positions: broadcastable to (..., S).
+    ``freqs`` replaces the plain inverse frequencies (YaRN), ``mscale``
+    scales cos and sin."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(head_dim, theta)                   # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]                        # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -28,13 +28,22 @@ class Model:
 
     # -- training -------------------------------------------------------
     def loss(self, params, batch) -> jax.Array:
-        logits, aux, _ = self.apply(params, batch)
-        return transformer.lm_loss(logits, batch["labels"], self.cfg, aux)
+        return self.loss_and_stats(params, batch)[0]
+
+    def loss_and_stats(self, params, batch):
+        """(loss, MoE routing totals: a dict, empty without MoE layers)."""
+        logits, aux, _, stats = self._forward(params, batch)
+        return transformer.lm_loss(logits, batch["labels"], self.cfg, aux,
+                                   aux_weight=self.cfg.aux_loss_alpha), stats
 
     def apply(self, params, batch, return_cache: bool = False,
               last_only: bool = False):
+        """(logits, aux loss, cache)."""
+        return self._forward(params, batch, return_cache=return_cache,
+                             last_only=last_only)[:3]
+
+    def _forward(self, params, batch, **kw):
         cfg = self.cfg
-        kw = dict(return_cache=return_cache, last_only=last_only)
         if cfg.frontend == "audio":
             return transformer.forward(params, cfg, self.rc,
                                        embeds=batch["embeds"], **kw)
@@ -52,6 +61,11 @@ class Model:
         return logits, cache
 
     def decode(self, params, cache, batch):
+        """(logits, cache)."""
+        return self.decode_and_stats(params, cache, batch)[:2]
+
+    def decode_and_stats(self, params, cache, batch):
+        """(logits, cache, MoE routing totals: empty without MoE layers)."""
         cfg = self.cfg
         if cfg.frontend == "audio":
             return transformer.decode_step(params, cfg, self.rc, cache,

@@ -11,6 +11,18 @@ One code path per family:
 Scan-over-layers keeps the HLO O(1) in depth: a 95-layer deepseek-67b
 train step lowers to one while-loop body. Params are stored stacked
 (leading L axis) so FSDP/TP shardings apply uniformly.
+
+MoE models with leading dense layers (``cfg.first_k_dense``,
+DeepSeek-V2) store their layers as stacks by kind, ``blocks = {"dense":
+(first_k_dense, ...), "moe": (rest, ...)}``, each scanned in turn; their
+cache holds one sub-dict per stack. Latent attention (``cfg.is_mla``) is
+chosen inside the block and the cache: it caches only the latent
+``c_kv`` and rotated ``k_pe`` of each position.
+
+Every block returns, beside its aux loss, the MoE layer's int32 routing
+totals (``moe.STAT_KEYS``; none for a dense block), summed over layers:
+``forward`` and ``decode_step`` return them (an empty dict without MoE
+layers).
 """
 from __future__ import annotations
 
@@ -20,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn_lib
+from repro.models import mla as mla_lib
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.layers import (RunConfig, apply_mlp, embed_init, init_mlp,
@@ -45,9 +58,10 @@ def _cast_params(params, rc: RunConfig):
 # ---------------------------------------------------------------------------
 def _init_attn_block(key, cfg, dtype, use_moe: bool):
     k1, k2 = jax.random.split(key)
+    init_attn = mla_lib.init_mla if cfg.is_mla else attn_lib.init_attention
     p = {
         "ln1": jnp.zeros((cfg.d_model,), jnp.float32),
-        "attn": attn_lib.init_attention(k1, cfg, dtype),
+        "attn": init_attn(k1, cfg, dtype),
         "ln2": jnp.zeros((cfg.d_model,), jnp.float32),
     }
     if use_moe:
@@ -83,7 +97,16 @@ def init_params(cfg, key, rc: RunConfig) -> Dict[str, Any]:
         params["head"] = embed_init(keys[1], (cfg.vocab_padded, cfg.d_model), dtype)
 
     L = cfg.n_layers
-    if cfg.family in ("dense", "audio", "vlm"):
+    if cfg.first_k_dense:
+        if cfg.family != "moe":
+            raise ValueError("first_k_dense needs an MoE model")
+        k = cfg.first_k_dense
+        params["blocks"] = {
+            "dense": jax.vmap(lambda kk: _init_attn_block(
+                kk, cfg, dtype, use_moe=False))(jax.random.split(keys[2], k)),
+            "moe": jax.vmap(lambda kk: _init_attn_block(
+                kk, cfg, dtype, use_moe=True))(jax.random.split(keys[3], L - k))}
+    elif cfg.family in ("dense", "audio", "vlm"):
         params["blocks"] = jax.vmap(
             lambda k: _init_attn_block(k, cfg, dtype, use_moe=False)
         )(jax.random.split(keys[2], L))
@@ -106,6 +129,20 @@ def init_params(cfg, key, rc: RunConfig) -> Dict[str, Any]:
             lambda k: _init_cross_block(k, cfg, dtype)
         )(jax.random.split(keys[4], n_cross))
     return params
+
+
+def _stacks(params, cfg):
+    """[(name, stacked block params, use_moe)] in order: the one stack
+    (name None), or the "dense" and "moe" stacks of a model with leading
+    dense layers."""
+    if cfg.first_k_dense:
+        return [(k, params["blocks"][k], k == "moe") for k in ("dense", "moe")]
+    return [(None, params["blocks"], cfg.family == "moe")]
+
+
+def _kv_names(cfg):
+    """The cache's entries of an attention layer."""
+    return ("c_kv", "k_pe") if cfg.is_mla else ("k", "v")
 
 
 # ---------------------------------------------------------------------------
@@ -138,19 +175,28 @@ def _residual_add(h, delta, rc: RunConfig, block_exit: bool = False):
 
 def _apply_attn_block(bp, h, cfg, rc, positions, *, cache=None, cache_index=None,
                       return_kv=False):
+    """Returns (h, kv, aux, stats); stats is {} for a dense MLP block."""
     x1 = _enter(rms_norm(h, bp["ln1"], cfg.norm_eps), rc)
-    a, kv = attn_lib.apply_attention(
-        bp["attn"], x1, cfg, rc, positions,
-        cache=cache, cache_index=cache_index, return_kv=return_kv)
+    if cfg.is_mla:
+        a, kv = mla_lib.apply_mla(bp["attn"], x1, cfg, rc, positions,
+                                  cache=cache, cache_index=cache_index)
+    else:
+        a, kv = attn_lib.apply_attention(
+            bp["attn"], x1, cfg, rc, positions,
+            cache=cache, cache_index=cache_index, return_kv=return_kv)
     h = _residual_add(h, a, rc)
-    aux = jnp.zeros((), jnp.float32)
+    aux, stats = jnp.zeros((), jnp.float32), {}
     x2 = _enter(rms_norm(h, bp["ln2"], cfg.norm_eps), rc)
     if "moe" in bp:
-        m, aux = moe_lib.apply_moe(bp["moe"], x2, cfg, rc)
+        m, aux, stats = moe_lib.apply_moe(bp["moe"], x2, cfg, rc)
     else:
         m = apply_mlp(bp["mlp"], x2, gelu=cfg.gelu_mlp)
     h = _residual_add(h, m, rc, block_exit=True)
-    return h, kv, aux
+    return h, kv, aux, stats
+
+
+def _add_stats(a, b):
+    return {k: a.get(k, 0) + b.get(k, 0) for k in {**a, **b}}
 
 
 def _apply_mamba_block(bp, h, cfg, rc, *, state=None, return_state=False):
@@ -186,11 +232,12 @@ def forward(params, cfg, rc: RunConfig, *, tokens=None, embeds=None,
             last_only: bool = False):
     """Full-sequence forward.
 
-    Returns (logits, aux_loss, cache) — cache is None unless
+    Returns (logits, aux_loss, cache, stats) — cache is None unless
     ``return_cache`` (prefill), and is a dict matching init_cache's
     structure with pos = S. ``last_only`` emits logits for the final
     position only (what serving prefill actually needs — skips the
-    (B,S,V) logits tensor entirely).
+    (B,S,V) logits tensor entirely). ``stats`` holds the MoE routing
+    totals (empty without MoE layers).
     """
     params = _cast_params(params, rc)
     if embeds is not None:
@@ -205,22 +252,32 @@ def forward(params, cfg, rc: RunConfig, *, tokens=None, embeds=None,
     positions = jnp.arange(S)[None, :]
 
     aux_total = jnp.zeros((), jnp.float32)
+    stats: Dict[str, Any] = {}
     cache: Optional[Dict[str, Any]] = {} if return_cache else None
+
+    def body(carry, bp):
+        hh, aux, st = carry
+        hh, kv, a, s = _apply_attn_block(bp, hh, cfg, rc, positions,
+                                         return_kv=return_cache)
+        return (hh, aux + a, _add_stats(st, s)), kv
+    body = _maybe_remat(body, rc)
 
     if cfg.family in ("dense", "moe", "audio", "vlm"):
         if cfg.family == "vlm" and img_embeds is not None:
             h, cache, aux_total = _vlm_forward(params, cfg, rc, h, positions,
                                                img_embeds, return_cache)
         else:
-            def body(carry, bp):
-                hh, aux = carry
-                hh, kv, a = _apply_attn_block(bp, hh, cfg, rc, positions,
-                                              return_kv=return_cache)
-                return (hh, aux + a), kv
-            body = _maybe_remat(body, rc)
-            (h, aux_total), kvs = jax.lax.scan(body, (h, aux_total), params["blocks"])
-            if return_cache:
-                cache = {"k": kvs[0], "v": kvs[1]}
+            for name, blocks, use_moe in _stacks(params, cfg):
+                st0 = moe_lib.zero_stats() if use_moe else {}
+                (h, aux_total, st), kvs = jax.lax.scan(
+                    body, (h, aux_total, st0), blocks)
+                stats = _add_stats(stats, st) if stats else st
+                if return_cache:
+                    sub = dict(zip(_kv_names(cfg), kvs))
+                    if name is None:
+                        cache.update(sub)
+                    else:
+                        cache[name] = sub
     elif cfg.family == "ssm":
         def body(carry, bp):
             hh, aux = carry
@@ -246,7 +303,7 @@ def forward(params, cfg, rc: RunConfig, *, tokens=None, embeds=None,
     logits = rc.constrain(logits, ("dp", None, "tp"))
     if return_cache and cache is not None:
         cache["pos"] = jnp.asarray(S, jnp.int32)
-    return logits, aux_total, cache
+    return logits, aux_total, cache, stats
 
 
 def _segments(n_layers: int, every: int):
@@ -279,8 +336,8 @@ def _hybrid_forward(params, cfg, rc, h, positions, return_cache):
         if return_cache:
             cache["ssm"].append(states)
         if full:
-            h, kv, a_ = _apply_attn_block(params["shared_block"], h, cfg, rc,
-                                          positions, return_kv=return_cache)
+            h, kv, a_, _ = _apply_attn_block(params["shared_block"], h, cfg, rc,
+                                             positions, return_kv=return_cache)
             aux = aux + a_
             if return_cache:
                 cache["k"].append(kv[0])
@@ -300,8 +357,8 @@ def _vlm_forward(params, cfg, rc, h, positions, img_embeds, return_cache):
 
     def body(carry, bp):
         hh = carry
-        hh, kv, _ = _apply_attn_block(bp, hh, cfg, rc, positions,
-                                      return_kv=return_cache)
+        hh, kv, _, _ = _apply_attn_block(bp, hh, cfg, rc, positions,
+                                         return_kv=return_cache)
         return hh, kv
     body = _maybe_remat(body, rc)
 
@@ -336,9 +393,20 @@ def init_cache(cfg, rc: RunConfig, batch: int, max_len: int):
     produces (modulo max_len sizing)."""
     K, hd, L = cfg.n_kv_heads, cfg.resolved_head_dim, cfg.n_layers
     cdt = rc.cdtype
+
+    def attn_cache(n):
+        if cfg.is_mla:       # latent entries only
+            return {"c_kv": jnp.zeros((n, batch, max_len, cfg.kv_lora_rank),
+                                      cdt),
+                    "k_pe": jnp.zeros((n, batch, max_len,
+                                       cfg.qk_rope_head_dim), cdt)}
+        return {"k": jnp.zeros((n, batch, max_len, K, hd), cdt),
+                "v": jnp.zeros((n, batch, max_len, K, hd), cdt)}
+
     if cfg.family in ("dense", "moe", "audio"):
-        c = {"k": jnp.zeros((L, batch, max_len, K, hd), cdt),
-             "v": jnp.zeros((L, batch, max_len, K, hd), cdt)}
+        c = ({"dense": attn_cache(cfg.first_k_dense),
+              "moe": attn_cache(L - cfg.first_k_dense)}
+             if cfg.first_k_dense else attn_cache(L))
     elif cfg.family == "vlm":
         n_cross = L // cfg.cross_attn_every
         c = {"k": jnp.zeros((L, batch, max_len, K, hd), cdt),
@@ -365,7 +433,8 @@ def init_cache(cfg, rc: RunConfig, batch: int, max_len: int):
 def decode_step(params, cfg, rc: RunConfig, cache, tokens, *, embeds=None):
     """One decode step. tokens: (B, 1) int32 (or embeds (B,1,D) for audio).
 
-    Returns (logits (B,1,Vp), new_cache)."""
+    Returns (logits (B,1,Vp), new_cache, stats): stats are the MoE
+    routing totals (empty without MoE layers)."""
     params = _cast_params(params, rc)
     index = cache["pos"]
     if embeds is not None:
@@ -378,14 +447,32 @@ def decode_step(params, cfg, rc: RunConfig, cache, tokens, *, embeds=None):
         if tokens is not None else jnp.full((h.shape[0], 1), index)
 
     new_cache = dict(cache)
+    stats: Dict[str, Any] = {}
     if cfg.family in ("dense", "moe", "audio"):
-        def body(hh, xs):
-            bp, kc, vc = xs
-            hh, kv, _ = _apply_attn_block(bp, hh, cfg, rc, positions,
-                                          cache=(kc, vc), cache_index=index)
-            return hh, kv
-        h, kvs = jax.lax.scan(body, h, (params["blocks"], cache["k"], cache["v"]))
-        new_cache["k"], new_cache["v"] = kvs
+        names = _kv_names(cfg)
+
+        def body(carry, xs):
+            hh, st = carry
+            bp, *kv = xs
+            hh, kv, _, s = _apply_attn_block(bp, hh, cfg, rc, positions,
+                                             cache=tuple(kv), cache_index=index)
+            return (hh, _add_stats(st, s)), kv
+        for name, blocks, use_moe in _stacks(params, cfg):
+            sub = cache if name is None else cache[name]
+            st0 = moe_lib.zero_stats() if use_moe else {}
+            (h, st), kvs = jax.lax.scan(
+                body, (h, st0), (blocks,) + tuple(sub[n] for n in names))
+            stats = _add_stats(stats, st) if stats else st
+            if cfg.is_mla:
+                # the new position's latent entries, written in place
+                sub = {n: mla_lib.write_cache(sub[n], e, index)
+                       for n, e in zip(names, kvs)}
+            else:
+                sub = dict(zip(names, kvs))
+            if name is None:
+                new_cache.update(sub)
+            else:
+                new_cache[name] = sub
     elif cfg.family == "vlm":
         h, new_cache = _vlm_decode(params, cfg, rc, h, positions, cache, index)
     elif cfg.family == "ssm":
@@ -406,7 +493,7 @@ def decode_step(params, cfg, rc: RunConfig, cache, tokens, *, embeds=None):
     if cfg.logit_softcap:
         logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
     new_cache["pos"] = index + 1
-    return logits, new_cache
+    return logits, new_cache, stats
 
 
 def _hybrid_decode(params, cfg, rc, h, positions, cache, index):
@@ -425,7 +512,7 @@ def _hybrid_decode(params, cfg, rc, h, positions, cache, index):
         h, states = jax.lax.scan(body, h, (_slice_stack(params["blocks"], a, b), seg_state))
         ssm_states.append(states)
         if full:
-            h, kv, _ = _apply_attn_block(
+            h, kv, _, _ = _apply_attn_block(
                 params["shared_block"], h, cfg, rc, positions,
                 cache=(cache["k"][app], cache["v"][app]), cache_index=index)
             ks.append(kv[0])
@@ -446,8 +533,8 @@ def _vlm_decode(params, cfg, rc, h, positions, cache, index):
 
     def body(hh, xs):
         bp, kc, vc = xs
-        hh, kv, _ = _apply_attn_block(bp, hh, cfg, rc, positions,
-                                      cache=(kc, vc), cache_index=index)
+        hh, kv, _, _ = _apply_attn_block(bp, hh, cfg, rc, positions,
+                                         cache=(kc, vc), cache_index=index)
         return hh, kv
 
     for a, b, full in _segments(cfg.n_layers, cfg.cross_attn_every):

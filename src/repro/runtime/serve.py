@@ -45,8 +45,11 @@ def build_prefill_step(cfg, mesh: Optional[Mesh], *, B: int, S: int,
 
 def build_decode_step(cfg, shape_cfg, mesh: Optional[Mesh], *,
                       rc: Optional[RunConfig] = None,
-                      policy: Optional[ShardingPolicy] = None):
-    """Decode one token against a cache of shape_cfg.seq_len.
+                      policy: Optional[ShardingPolicy] = None,
+                      with_stats: bool = False):
+    """Decode one token against a cache of shape_cfg.seq_len. The step
+    returns (logits, cache), and ``with_stats`` a third output: the MoE
+    routing totals as ``moe.<name>`` metrics.
 
     Returns (jitted, params_sds, cache_sds, batch_sds, shardings, model)."""
     policy = policy or ShardingPolicy()
@@ -61,7 +64,10 @@ def build_decode_step(cfg, shape_cfg, mesh: Optional[Mesh], *,
     batch_sds = decode_batch_specs(cfg, B)
 
     def decode(params, cache, batch):
-        return model.decode(params, cache, batch)
+        if not with_stats:
+            return model.decode(params, cache, batch)
+        logits, cache, stats = model.decode_and_stats(params, cache, batch)
+        return logits, cache, {"moe." + k: v for k, v in stats.items()}
 
     if mesh is None:
         jitted = jax.jit(decode, donate_argnums=(1,))
@@ -70,6 +76,7 @@ def build_decode_step(cfg, shape_cfg, mesh: Optional[Mesh], *,
     p_sh = to_named(param_specs(params_sds, mesh, policy), mesh)
     c_sh = to_named(cache_specs(cache_sds, mesh, cfg, shape_cfg, policy), mesh)
     b_sh = to_named(batch_specs(batch_sds, mesh, policy), mesh)
+    outs = (None, c_sh, None) if with_stats else (None, c_sh)
     jitted = jax.jit(decode, in_shardings=(p_sh, c_sh, b_sh),
-                     out_shardings=(None, c_sh), donate_argnums=(1,))
+                     out_shardings=outs, donate_argnums=(1,))
     return jitted, params_sds, cache_sds, batch_sds, (p_sh, c_sh, b_sh), model

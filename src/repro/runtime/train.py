@@ -33,7 +33,7 @@ def make_train_step(model: Model, trc: TrainRunConfig):
     """Pure train step (no sharding — composable under jit or plain CPU)."""
 
     def loss_fn(params, batch):
-        return model.loss(params, batch)
+        return model.loss_and_stats(params, batch)
 
     def train_step(state: TrainState, batch):
         if trc.grad_accum > 1:
@@ -46,24 +46,28 @@ def make_train_step(model: Model, trc: TrainRunConfig):
 
             def acc(carry, mb):
                 gsum, lsum = carry
-                l, g = jax.value_and_grad(loss_fn)(state.params, mb)
+                (l, st), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                    state.params, mb)
                 gsum = jax.tree.map(
                     lambda s, x: s + x.astype(jnp.float32), gsum, g)
-                return (gsum, lsum + l), None
+                return (gsum, lsum + l), st
 
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-            (gsum, lsum), _ = jax.lax.scan(acc, (zeros, jnp.zeros((), jnp.float32)), micro)
+            (gsum, lsum), sts = jax.lax.scan(acc, (zeros, jnp.zeros((), jnp.float32)), micro)
             grads = jax.tree.map(lambda g: g / a, gsum)
             loss = lsum / a
+            stats = jax.tree.map(lambda x: x.sum(0), sts)
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+            (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                state.params, batch)
 
         if trc.compression == "int8":
             grads = comp_lib.quantize_dequantize_int8(grads)
 
         new_state, metrics = apply_updates(state, grads, trc.opt)
         metrics["loss"] = loss
+        metrics.update({"moe." + k: v for k, v in stats.items()})
         return new_state, metrics
 
     return train_step

@@ -27,7 +27,8 @@ def _batch(cfg, B=2, S=32, seed=1):
 
 
 def test_all_ten_archs_registered():
-    assert len(ARCHS) == 10
+    # the ten assigned architectures and deepseek-v2-lite
+    assert len(ARCHS) == 11
     families = {REGISTRY[a].family for a in ARCHS}
     assert families == {"dense", "moe", "ssm", "hybrid", "audio", "vlm"}
 
@@ -91,6 +92,7 @@ def test_param_counts_match_published_sizes():
         "qwen2-0.5b": (0.4e9, 0.65e9),
         "musicgen-medium": (1.3e9, 2.1e9),
         "llama-3.2-vision-11b": (9e9, 11e9),      # minus the vision stub
+        "deepseek-v2-lite": (15.2e9, 16.2e9),     # 15.7B total published
     }
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
@@ -98,6 +100,6 @@ def test_param_counts_match_published_sizes():
 
 
 def test_moe_active_params_below_total():
-    for arch in ("llama4-scout-17b-a16e", "qwen2-moe-a2.7b"):
+    for arch in ("llama4-scout-17b-a16e", "qwen2-moe-a2.7b", "deepseek-v2-lite"):
         cfg = get_config(arch)
         assert cfg.active_param_count() < 0.5 * cfg.param_count()
