@@ -42,15 +42,42 @@ from repro.models.layers import (RunConfig, apply_mlp, embed_init, init_mlp,
 _KEEP_F32 = ("A_log", "dt_bias", "D_skip", "router", "gate")
 
 
-def _cast_params(params, rc: RunConfig):
+def _cast_params(params, rc: RunConfig,
+                 convert=lambda leaf, dtype: leaf.astype(dtype)):
+    """``params`` with every floating leaf but the ``_KEEP_F32`` ones in
+    the compute dtype, each cast by ``convert(leaf, dtype)``."""
     def cast(path, leaf):
-        name = str(path[-1].key) if hasattr(path[-1], "key") else ""
+        name = str(path[-1].key) if path and hasattr(path[-1], "key") else ""
         if any(k in name for k in _KEEP_F32):
             return leaf
         if jnp.issubdtype(leaf.dtype, jnp.floating):
-            return leaf.astype(rc.cdtype)
+            return convert(leaf, rc.cdtype)
         return leaf
     return jax.tree_util.tree_map_with_path(cast, params)
+
+
+def _astype_in_place(x, dtype):
+    """``x.astype(dtype)``, with float32 -> bfloat16 written as integer
+    ops: round to nearest, ties to even, and a NaN to the quiet NaN of its
+    sign, as ``astype`` rounds.
+
+    The TPU compiler moves a plain narrowing convert of a scanned weight
+    slice ahead of the scan's slicing and out of the loop, where it
+    writes a bfloat16 copy of the whole stack every call; these integer
+    ops stay where they are written."""
+    if x.dtype != jnp.float32 or dtype != jnp.bfloat16:
+        return x.astype(dtype)
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    r = (u + 0x7FFF + ((u >> 16) & 1)) >> 16
+    r = jnp.where((u & 0x7FFFFFFF) > 0x7F800000, ((u >> 16) & 0x8000) | 0x7FC0, r)
+    return jax.lax.bitcast_convert_type(r.astype(jnp.uint16), jnp.bfloat16)
+
+
+def _cast_at_use(tree, rc: RunConfig):
+    """Decode's cast of what a step is about to use: a layer's slice in
+    the scan body, the gathered embedding rows, the head. The weights are
+    read once, in their stored dtype, where they are used."""
+    return _cast_params(tree, rc, _astype_in_place)
 
 
 # ---------------------------------------------------------------------------
@@ -434,13 +461,13 @@ def decode_step(params, cfg, rc: RunConfig, cache, tokens, *, embeds=None):
     """One decode step. tokens: (B, 1) int32 (or embeds (B,1,D) for audio).
 
     Returns (logits (B,1,Vp), new_cache, stats): stats are the MoE
-    routing totals (empty without MoE layers)."""
-    params = _cast_params(params, rc)
+    routing totals (empty without MoE layers). Each weight is cast to the
+    compute dtype where it is used (``_cast_at_use``)."""
     index = cache["pos"]
     if embeds is not None:
         h = embeds.astype(rc.cdtype)
     else:
-        h = jnp.take(params["embed"], tokens, axis=0)
+        h = _cast_at_use(jnp.take(params["embed"], tokens, axis=0), rc)
     if cfg.scale_embeddings:
         h = h * jnp.asarray(cfg.d_model ** 0.5, rc.cdtype)
     positions = jnp.broadcast_to(index[None, None], tokens.shape[:1] + (1,)) \
@@ -454,7 +481,8 @@ def decode_step(params, cfg, rc: RunConfig, cache, tokens, *, embeds=None):
         def body(carry, xs):
             hh, st = carry
             bp, *kv = xs
-            hh, kv, _, s = _apply_attn_block(bp, hh, cfg, rc, positions,
+            hh, kv, _, s = _apply_attn_block(_cast_at_use(bp, rc), hh, cfg,
+                                             rc, positions,
                                              cache=tuple(kv), cache_index=index)
             return (hh, _add_stats(st, s)), kv
         for name, blocks, use_moe in _stacks(params, cfg):
@@ -478,7 +506,8 @@ def decode_step(params, cfg, rc: RunConfig, cache, tokens, *, embeds=None):
     elif cfg.family == "ssm":
         def body(hh, xs):
             bp, st = xs
-            hh, st2 = _apply_mamba_block(bp, hh, cfg, rc, state=ssm_lib.SSMState(*st))
+            hh, st2 = _apply_mamba_block(_cast_at_use(bp, rc), hh, cfg, rc,
+                                         state=ssm_lib.SSMState(*st))
             return hh, tuple(st2)
         h, states = jax.lax.scan(body, h, (params["blocks"], tuple(cache["ssm"])))
         new_cache["ssm"] = ssm_lib.SSMState(*states)
@@ -487,9 +516,9 @@ def decode_step(params, cfg, rc: RunConfig, cache, tokens, *, embeds=None):
     else:
         raise ValueError(cfg.family)
 
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    h = rms_norm(h, _cast_at_use(params["final_norm"], rc), cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = jnp.einsum("bsd,vd->bsv", h, head)
+    logits = jnp.einsum("bsd,vd->bsv", h, _cast_at_use(head, rc))
     if cfg.logit_softcap:
         logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
     new_cache["pos"] = index + 1
@@ -504,7 +533,8 @@ def _hybrid_decode(params, cfg, rc, h, positions, cache, index):
 
     def body(hh, xs):
         bp, st = xs
-        hh, st2 = _apply_mamba_block(bp, hh, cfg, rc, state=ssm_lib.SSMState(*st))
+        hh, st2 = _apply_mamba_block(_cast_at_use(bp, rc), hh, cfg, rc,
+                                     state=ssm_lib.SSMState(*st))
         return hh, tuple(st2)
 
     for a, b, full in _segments(cfg.n_layers, cfg.attn_every):
@@ -513,7 +543,7 @@ def _hybrid_decode(params, cfg, rc, h, positions, cache, index):
         ssm_states.append(states)
         if full:
             h, kv, _, _ = _apply_attn_block(
-                params["shared_block"], h, cfg, rc, positions,
+                _cast_at_use(params["shared_block"], rc), h, cfg, rc, positions,
                 cache=(cache["k"][app], cache["v"][app]), cache_index=index)
             ks.append(kv[0])
             vs.append(kv[1])
@@ -533,8 +563,9 @@ def _vlm_decode(params, cfg, rc, h, positions, cache, index):
 
     def body(hh, xs):
         bp, kc, vc = xs
-        hh, kv, _, _ = _apply_attn_block(bp, hh, cfg, rc, positions,
-                                         cache=(kc, vc), cache_index=index)
+        hh, kv, _, _ = _apply_attn_block(_cast_at_use(bp, rc), hh, cfg, rc,
+                                         positions, cache=(kc, vc),
+                                         cache_index=index)
         return hh, kv
 
     for a, b, full in _segments(cfg.n_layers, cfg.cross_attn_every):
@@ -544,7 +575,8 @@ def _vlm_decode(params, cfg, rc, h, positions, cache, index):
         ks.append(kvs[0])
         vs.append(kvs[1])
         if full and ci < n_cross:
-            cb = jax.tree.map(lambda p: p[ci], params["cross_blocks"])
+            cb = _cast_at_use(jax.tree.map(lambda p: p[ci],
+                                           params["cross_blocks"]), rc)
             h, _ = _apply_cross_block(cb, h, cfg, rc, None,
                                       cache=(cache["xk"][ci], cache["xv"][ci]))
             ci += 1

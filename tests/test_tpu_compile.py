@@ -8,6 +8,9 @@ fixture (never at import), so every xdist worker collects the same tests
 and only the worker given this file loads the TPU library.
 """
 import importlib.util
+import json
+import re
+import sys
 from pathlib import Path
 
 import jax
@@ -25,10 +28,11 @@ from repro.runtime.serve import build_decode_step, build_prefill_step
 from repro.runtime.train import TrainRunConfig, build_train_step
 
 HBM_BYTES = 15.75e9           # what the v5e compiler lets one program use
+REPO = Path(__file__).resolve().parent.parent
 
 
 def _smoke():
-    path = Path(__file__).resolve().parent.parent / "chip_smoke.py"
+    path = REPO / "chip_smoke.py"
     spec = importlib.util.spec_from_file_location("chip_smoke", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -135,3 +139,52 @@ def test_sharded_train_step_compiles_on_2x2(topo):
     _fits(compiled)                           # per-device bytes
     text = compiled.as_text()
     assert "all-reduce" in text and "all-gather" in text
+
+
+def _bench_mla_moe():
+    """The dsv2lite.train8k cell's model and program configuration."""
+    if str(REPO) not in sys.path:
+        sys.path.insert(0, str(REPO))
+    from bench.kinds.ml_pipeline_mla_moe import arch_config
+    m = json.loads((REPO / "bench/configs/mlpipe-deepseek-v2-lite.json")
+                   .read_text())
+    run = m["run"]
+    rc = RunConfig(param_dtype=run["param_dtype"],
+                   compute_dtype=run["compute_dtype"],
+                   attn_chunk=run["attn_chunk"],
+                   attn_dense_max=run["attn_dense_max"],
+                   moe_group=run["moe_group"])
+    return arch_config(m, m["name"]), rc
+
+
+def _entry_bf16_casts(text):
+    """Shapes of the bf16 arrays the entry computation's converts and
+    fusions make."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    return {tuple(map(int, dims.split(","))) for dims in re.findall(
+        r"= bf16\[([\d,]+)\]\S* (?:convert|fusion)\(", entry)}
+
+
+@pytest.mark.parametrize("arch,B,cache", [("qwen2-0.5b", 8, 1024),
+                                          ("mlpipe-deepseek-v2-lite", 4, 8192)])
+def test_decode_casts_weights_inside_the_layer_scan(one_chip, arch, B, cache):
+    """The f32 weight matrices reach the decode program's layer loop as
+    they are stored: the entry computation casts no layer stack (nor the
+    embedding or head) to bf16. A stack of one layer is left out: XLA
+    drops its one-trip loop, so its cast is that layer's."""
+    if arch == "qwen2-0.5b":
+        cfg, rc = get_config(arch), RunConfig()
+    else:
+        cfg, rc = _bench_mla_moe()
+    fn, params, kv, batch, *_ = build_decode_step(
+        cfg, ShapeConfig("decode", "decode", cache, B), None, rc=rc,
+        with_stats=cfg.n_experts > 0)
+    compiled = fn.lower(*(_on(one_chip, a) for a in (params, kv, batch))
+                        ).compile()
+    _fits(compiled)
+    weights = {w.shape for w in jax.tree.leaves(params["blocks"])
+               if w.ndim > 2 and w.shape[0] > 1 and w.dtype == jnp.float32}
+    weights |= {params[k].shape for k in ("embed", "head") if k in params}
+    cast = _entry_bf16_casts(compiled.as_text())
+    assert len(weights) >= 5 and not weights & cast, sorted(weights & cast)
