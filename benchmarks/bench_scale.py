@@ -11,7 +11,7 @@ tenant (Poisson surge, the whole queue arriving in the first ~minute),
 so the admission backlog grows to thousands of pending requests while
 interactive load keeps flowing. Per admission policy the run records
 real wall-clock, sim events/sec, *events per pod* (the 10k-tier
-bottleneck ISSUE 3 attacks), queue backend, usage-accounting mode,
+bottleneck ISSUE 3 attacks), usage-accounting mode,
 peak pending depths, per-tenant makespan, and peak RSS, then writes
 everything to ``BENCH_scale.json`` (``bench_scale/v2`` schema:
 benchmarks/README.md).
@@ -21,8 +21,7 @@ Usage:
         [--workflows 1000] [--nodes 100] [--workers 1] \
         [--tiers 1000x100,10000x1000,100000x1000,1000000x8000x8] \
         [--seed 42] [--policies fifo,priority,fair-share,drf,quota,preempt] \
-        [--queue calendar|heap] [--usage-mode event|sampled] \
-        [--lifecycle fast|chained] [--trace examples/trace_mixed.json] \
+        [--usage-mode event|sampled] [--trace examples/trace_mixed.json] \
         [--out BENCH_scale.json] [--budget-s 0] [--profile] \
         [--min-events-per-sec 0] [--max-events-per-pod 0] \
         [--max-peak-rss-mib 0] [--max-shard-rss-mib 0] [--shard-procs 0] \
@@ -179,6 +178,9 @@ sets the eventual-completion floor for the overload tier (e.g. 0.99).
 the chaos plane to the gate->arbiter hop: dropped submissions are
 redelivered from the WAL, duplicates are suppressed by the dedup set.
 
+v9 rows drop ``queue`` and ``lifecycle``: the sim has one event queue
+and one pod lifecycle, so neither field can vary.
+
 The script still runs against the pre-optimization core (counters it
 introduced are read via getattr) so speedups can be measured by
 checking out two revisions and comparing ``wall_s``.
@@ -212,10 +214,10 @@ BATCH_DEADLINE_S = 3600.0
 # (sum over the 8 streams = 120%, so caps genuinely bind under load)
 PROD_QUOTA_FRAC = 0.20
 BATCH_QUOTA_FRAC = 0.10
-SCHEMA = "bench_scale/v8"
+SCHEMA = "bench_scale/v9"
 
 
-def _plane_kwargs(usage_mode, queue, lifecycle, placement="first-fit",
+def _plane_kwargs(usage_mode, placement="first-fit",
                   deschedule=None, autoscale=None, gateway=None):
     """Knobs that only the optimized core understands."""
     params = inspect.signature(ControlPlane.__init__).parameters
@@ -226,10 +228,6 @@ def _plane_kwargs(usage_mode, queue, lifecycle, placement="first-fit",
         kw["retain_pod_log"] = False
     if "usage_mode" in params:
         kw["usage_mode"] = usage_mode
-    if "queue" in params and queue:
-        kw["queue"] = queue
-    if "lifecycle" in params and lifecycle:
-        kw["lifecycle"] = lifecycle
     if "placement" in params and placement != "first-fit":
         kw["placement"] = placement
     if "deschedule" in params and deschedule is not None:
@@ -250,11 +248,10 @@ def _cluster_cfg(n_nodes, node_mix="uniform"):
 
 
 def build_plane(policy, n_workflows, n_nodes, seed, usage_mode="event",
-                queue=None, lifecycle=None, trace=None, workers=1,
-                shard_procs=None, processes=True, profile=False,
-                chaos=None, placement="first-fit", node_mix="uniform",
-                deschedule=None, autoscale=None, gateway=None,
-                wal_dir=None):
+                trace=None, workers=1, shard_procs=None, processes=True,
+                profile=False, chaos=None, placement="first-fit",
+                node_mix="uniform", deschedule=None, autoscale=None,
+                gateway=None, wal_dir=None):
     cfg = _cluster_cfg(n_nodes, node_mix)
     if workers > 1:
         from repro.core.shard import ShardedControlPlane
@@ -267,8 +264,8 @@ def build_plane(policy, n_workflows, n_nodes, seed, usage_mode="event",
             fold_completed=True, capture_trace=False,
             shard_procs=shard_procs, processes=processes, profile=profile,
             chaos=chaos, **extra,
-            **_plane_kwargs(usage_mode, queue, lifecycle,
-                            placement, deschedule, autoscale, gateway))
+            **_plane_kwargs(usage_mode, placement, deschedule, autoscale,
+                            gateway))
     else:
         extra = {}
         if gateway is not None and wal_dir:
@@ -277,9 +274,9 @@ def build_plane(policy, n_workflows, n_nodes, seed, usage_mode="event",
         plane = ControlPlane("kubeadaptor", admission_policy=policy,
                              cluster_cfg=cfg,
                              seed=seed, chaos=chaos, **extra,
-                             **_plane_kwargs(usage_mode, queue, lifecycle,
-                                             placement, deschedule,
-                                             autoscale, gateway))
+                             **_plane_kwargs(usage_mode, placement,
+                                             deschedule, autoscale,
+                                             gateway))
     if trace is not None:
         plane.add_trace(trace.get("arrivals", []),
                         tenants=trace.get("tenants"))
@@ -345,7 +342,7 @@ def _round_gateway(snap):
 
 
 def run_policy(policy, n_workflows, n_nodes, seed, horizon_s=400_000.0,
-               usage_mode="event", queue=None, lifecycle=None, trace=None,
+               usage_mode="event", trace=None,
                profile=False, workers=1, shard_procs=None, chaos=None,
                placement="first-fit", node_mix="uniform", deschedule=None,
                autoscale=None, gateway=None, wal_dir=None):
@@ -358,14 +355,12 @@ def run_policy(policy, n_workflows, n_nodes, seed, horizon_s=400_000.0,
     if workers > 1:
         return _run_policy_sharded(
             policy, n_workflows, n_nodes, seed, horizon_s=horizon_s,
-            usage_mode=usage_mode, queue=queue, lifecycle=lifecycle,
-            trace=trace, profile=profile, workers=workers,
-            shard_procs=shard_procs, chaos=chaos, placement=placement,
-            node_mix=node_mix, deschedule=deschedule, autoscale=autoscale,
-            gateway=gateway, wal_dir=wal_dir)
+            usage_mode=usage_mode, trace=trace, profile=profile,
+            workers=workers, shard_procs=shard_procs, chaos=chaos,
+            placement=placement, node_mix=node_mix, deschedule=deschedule,
+            autoscale=autoscale, gateway=gateway, wal_dir=wal_dir)
     plane = build_plane(policy, n_workflows, n_nodes, seed,
-                        usage_mode=usage_mode, queue=queue,
-                        lifecycle=lifecycle, trace=trace, chaos=chaos,
+                        usage_mode=usage_mode, trace=trace, chaos=chaos,
                         placement=placement, node_mix=node_mix,
                         deschedule=deschedule, autoscale=autoscale,
                         gateway=gateway, wal_dir=wal_dir)
@@ -409,9 +404,7 @@ def run_policy(policy, n_workflows, n_nodes, seed, horizon_s=400_000.0,
         "pods_created": pods,
         "events_per_pod": (round(events / pods, 2)
                            if events and pods else None),
-        "queue": getattr(res.sim, "queue_name", "heap"),
         "usage_mode": getattr(m, "usage_mode", "sampled"),
-        "lifecycle": getattr(res.cluster, "lifecycle", "chained"),
         "peak_pending_admission": getattr(res.arbiter, "max_pending", None),
         "peak_pending_pods": getattr(res.cluster, "max_pending_pods", None),
         "completed_workflows": completed,
@@ -520,8 +513,8 @@ def run_policy(policy, n_workflows, n_nodes, seed, horizon_s=400_000.0,
 
 
 def _run_policy_sharded(policy, n_workflows, n_nodes, seed,
-                        horizon_s=400_000.0, usage_mode="event", queue=None,
-                        lifecycle=None, trace=None, profile=False,
+                        horizon_s=400_000.0, usage_mode="event",
+                        trace=None, profile=False,
                         workers=2, shard_procs=None, chaos=None,
                         placement="first-fit", node_mix="uniform",
                         deschedule=None, autoscale=None, gateway=None,
@@ -532,8 +525,7 @@ def _run_policy_sharded(policy, n_workflows, n_nodes, seed,
     import os as _os
 
     plane = build_plane(policy, n_workflows, n_nodes, seed,
-                        usage_mode=usage_mode, queue=queue,
-                        lifecycle=lifecycle, trace=trace, workers=workers,
+                        usage_mode=usage_mode, trace=trace, workers=workers,
                         shard_procs=shard_procs, profile=profile,
                         chaos=chaos, placement=placement, node_mix=node_mix,
                         deschedule=deschedule, autoscale=autoscale,
@@ -580,9 +572,7 @@ def _run_policy_sharded(policy, n_workflows, n_nodes, seed,
         "pods_created": pods,
         "events_per_pod": (round(events / pods, 2)
                            if events and pods else None),
-        "queue": res.shards[0]["queue"],
         "usage_mode": res.shards[0]["usage_mode"],
-        "lifecycle": res.shards[0]["lifecycle"],
         "peak_pending_admission": res.peak_pending_admission,
         "peak_pending_pods": res.peak_pending_pods,
         "completed_workflows": res.completed_workflows,
@@ -689,12 +679,12 @@ def _run_policy_sharded(policy, n_workflows, n_nodes, seed,
 
 
 def run_scenario(n_workflows, n_nodes, seed, policies, usage_mode="event",
-                 queue=None, lifecycle=None, trace=None, trace_path=None,
+                 trace=None, trace_path=None,
                  profile=False, workers=1, shard_procs=None, chaos=None,
                  placement="first-fit", node_mix="uniform", deschedule=None,
                  autoscale=None, gateway=None, wal_dir=None):
     runs = [run_policy(p, n_workflows, n_nodes, seed, usage_mode=usage_mode,
-                       queue=queue, lifecycle=lifecycle, trace=trace,
+                       trace=trace,
                        profile=profile, workers=workers,
                        shard_procs=shard_procs, chaos=chaos,
                        placement=placement, node_mix=node_mix,
@@ -809,12 +799,8 @@ def main():
                          "waves — see README on events_per_sec")
     ap.add_argument("--seed", type=int, default=42)
     ap.add_argument("--policies", default=",".join(POLICIES))
-    ap.add_argument("--queue", default="",
-                    choices=("", "calendar", "heap"))
     ap.add_argument("--usage-mode", default="event",
                     choices=("event", "sampled"))
-    ap.add_argument("--lifecycle", default="",
-                    choices=("", "fast", "chained"))
     ap.add_argument("--trace", default="",
                     help="arrival_trace/v1 JSON to replay instead of the "
                          "synthetic streams")
@@ -996,8 +982,6 @@ def main():
     for n_wf, n_nodes, n_workers in _parse_tiers(args):
         tier = run_scenario(n_wf, n_nodes, args.seed, policies,
                             usage_mode=args.usage_mode,
-                            queue=args.queue or None,
-                            lifecycle=args.lifecycle or None,
                             trace=trace, trace_path=args.trace or None,
                             profile=args.profile, workers=n_workers,
                             shard_procs=args.shard_procs or None,

@@ -29,12 +29,10 @@ Scale-out notes (1000 workflows / 100 nodes — see ISSUE 2):
     event, with one object snapshot per notification, delivered at the
     same virtual times as the per-event path it replaces.
 
-Pod-lifecycle fast path (10k workflows / 1000 nodes — see ISSUE 3):
-the create→bind→running→succeeded→delete chain used to cost one sim
-event per pod per hop.  Every hop's *due time* is fixed by a constant
-latency, so same-instant hops coalesce into compound batch events that
-replay the per-pod callbacks in the exact order the chained events
-would have executed:
+Pod lifecycle: every hop of the create→bind→running→succeeded→delete
+chain has its *due time* fixed by a constant latency, so same-instant
+hops coalesce into compound batch events that run the per-pod
+callbacks in the order one event per hop would have run them:
 
   * pod creations scheduled at one instant share one apiserver event
     (``_flush_creates``), and deletions share a two-stage batch
@@ -52,11 +50,9 @@ batch that replays them back-to-back preserves every same-instant
 ordering; hops whose sequence numbers shift (e.g. a finish group
 scheduled after its siblings' notifications) only target instants
 reachable from distinct constant-latency sums, where no foreign event
-can sit between the old and new position.  ``lifecycle="chained"``
-(or ``REPRO_LIFECYCLE=chained``) restores the one-event-per-hop path;
-tests/test_event_core.py pins both paths to identical binding
-sequences and metrics, and tests/test_scale_core.py's pinned hashes
-run on the fast path.
+can sit between the old and new position.  tests/test_scale_core.py
+pins the binding sequences and tests/test_event_core.py the workflow
+records and event count of a stress scenario.
 
 Usage accounting: the cluster maintains exact in-use cpu/mem totals
 (``cpu_in_use``/``mem_in_use``, updated at bind/release) so ``used()``
@@ -101,7 +97,6 @@ shards by plain summation.
 from __future__ import annotations
 
 import ctypes
-import os
 import random
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -252,23 +247,15 @@ class Cluster:
                  cluster_cfg: cal.PaperCluster = cal.DEFAULT_CLUSTER,
                  payload_mode: str = "virtual", seed: int = 0,
                  retain_pod_log: bool = True,
-                 lifecycle: Optional[str] = None,
                  placement: str = "first-fit"):
         self.sim = sim
         self.p = params
-        if lifecycle is None:
-            lifecycle = os.environ.get("REPRO_LIFECYCLE", "fast")
-        if lifecycle not in ("fast", "chained"):
-            raise ValueError(f"unknown lifecycle {lifecycle!r}; "
-                             f"expected 'fast' or 'chained'")
-        self.lifecycle = lifecycle
         if placement not in self.PLACEMENTS:
             raise ValueError(f"unknown placement {placement!r}; "
                              f"expected one of {sorted(self.PLACEMENTS)}")
         self.placement = "scored-spread" if placement == "scored" \
             else placement
         self._score_mode = self.PLACEMENTS[placement]
-        self._fast = lifecycle == "fast"
         self._watch_lat = params.watch_latency   # hoisted: read per notify
         self.payload_mode = payload_mode
         self.rng = random.Random(seed)
@@ -288,7 +275,7 @@ class Cluster:
         # kind -> (delivery time, events) for the open same-instant batch
         self._watch_buf: Dict[str, Tuple[float, List[WatchEvent]]] = {}
         self._sched_scheduled = False
-        # fast-lifecycle coalescing buffers: (due instant, open batch)
+        # lifecycle coalescing buffers: (due instant, open batch)
         self._create_buf: Optional[Tuple[float, List]] = None
         self._del_buf: Optional[Tuple[float, List]] = None
         self._start_buf: Optional[Tuple[float, List[PodObj]]] = None
@@ -486,10 +473,6 @@ class Cluster:
             self.sim.after(self.p.api_latency, error_cb,
                            note="api-fault", args=("Unavailable", pod))
             return
-        if not self._fast:
-            self.sim.after(self.p.api_latency, self._create_now,
-                           args=(pod, cb, error_cb))
-            return
         # same-instant creations share one apiserver round-trip event
         due = self.sim.t + self.p.api_latency
         buf = self._create_buf
@@ -544,10 +527,6 @@ class Cluster:
                            note="api-fault",
                            args=("Unavailable", (namespace, name)))
             return
-        if not self._fast:
-            self.sim.after(self.p.api_latency, self._delete_lookup,
-                           args=(namespace, name, cb))
-            return
         # same-instant deletions share the apiserver lookup event and
         # one removal event pod_delete_latency later
         due = self.sim.t + self.p.api_latency
@@ -559,16 +538,6 @@ class Cluster:
         self._del_buf = (due, batch)
         self.sim.at(due, self._flush_delete_lookups, note="pod-delete",
                     args=(due, batch))
-
-    def _delete_lookup(self, namespace: str, name: str,
-                       cb: Optional[Callable]):
-        pod = self.pods.get((namespace, name))
-        if pod is None:
-            if cb:
-                cb(None)
-            return
-        self.sim.after(self.p.pod_delete_latency, self._remove_batch,
-                       args=([(pod, cb)],))
 
     def _flush_delete_lookups(self, due: float, batch: List):
         buf = self._del_buf
@@ -799,9 +768,6 @@ class Cluster:
         start_lat = self.p.pod_start_latency
         if pod.volume:
             start_lat += self.p.pvc_mount_latency
-        if not self._fast:
-            self.sim.after(start_lat, self._start, args=(pod,))
-            return
         # compound timeline: every pod bound in this scheduler cycle
         # shares one start event; the rest of its lifecycle (finish
         # instants, watch notifications) is laid out when it fires
@@ -848,17 +814,12 @@ class Cluster:
                             note="chaos-crash", args=(pod, FAILED))
         return self.sim.t + (dur if dur > 0.0 else 0.0)
 
-    def _start(self, pod: PodObj):
-        fdue = self._start_one(pod)
-        if fdue >= 0.0:
-            self.sim.at(fdue, self._finish, args=(pod, SUCCEEDED))
-
     def _start_batch(self, due: float, pods: List[PodObj]):
         buf = self._start_buf
         if buf is not None and buf[0] == due:
             self._start_buf = None
         # transition every pod first (their RUNNING notifications share
-        # one watch batch, in bind order — exactly the chained order),
+        # one watch batch, in bind order),
         # then schedule one finish event per distinct completion instant
         groups: Dict[float, List[PodObj]] = {}
         for pod in pods:
@@ -983,9 +944,9 @@ class Cluster:
         pod._rv += 1
         self.pods_lost += 1
         if pod.phase == PENDING:
-            # bound but not yet started: the pending _start event will
-            # no-op on the phase guard; release and fail directly (the
-            # _finish path only handles RUNNING pods)
+            # bound but not yet started: the pending _start_batch event
+            # skips it on _start_one's phase guard; release and fail
+            # directly (the _finish path only handles RUNNING pods)
             self._pending_pods.pop((pod.namespace, pod.name), None)
             self._release(pod)
             pod.phase = FAILED

@@ -5,23 +5,13 @@ emit events ('pod-succeeded', 'pod-deleted', ...), registered callbacks
 respond in the same virtual instant — the quick create/destroy switch
 the paper credits for its resource-usage advantage.
 
-Dispatch passes positional args through the sim's event record (no
-per-callback lambda allocation on the hot pod-lifecycle path).
-
-Scale fast path (ISSUE 3): same-instant dispatches coalesce.  The old
-path scheduled one zero-delay sim event per callback per emit — two
-per pod (pod-succeeded, pod-removed) on the lifecycle hot path.  Now
-the first emit of an instant opens a dispatch buffer and schedules one
-flush; subsequent emits at that instant append.  The flush fires the
-callbacks in exact emit order at the same virtual instant and with the
-same position in the instant's event sequence the first per-callback
-event would have had (callbacks scheduled between two emits of one
-instant can only target *later* times, so nothing can interleave —
-the same argument that makes the cluster's lifecycle batches exact).
-Emits issued *during* a flush open a fresh buffer, matching the old
-behaviour of a nested emit queuing behind the current event.
-``EventRegistry(sim, batched=False)`` restores the per-callback path
-(the ControlPlane ties it to its ``lifecycle="chained"`` mode).
+Same-instant dispatches coalesce: the first emit of an instant opens a
+dispatch buffer and schedules one flush; later emits at that instant
+append.  The flush fires the callbacks in exact emit order, passing
+their positional args through (no per-callback event or lambda).
+Callbacks scheduled between two emits of one instant can only target
+later times, so nothing can interleave with the batch.  Emits issued
+*during* a flush open a fresh buffer, queuing behind the current event.
 """
 from __future__ import annotations
 
@@ -32,9 +22,8 @@ from repro.core.sim import Sim
 
 
 class EventRegistry:
-    def __init__(self, sim: Sim, batched: bool = True):
+    def __init__(self, sim: Sim):
         self.sim = sim
-        self.batched = batched
         self._subs: Dict[str, List[Callable]] = defaultdict(list)
         self.emitted: Dict[str, int] = defaultdict(int)
         # open same-instant dispatch batch: (instant, [(cb, args), ...])
@@ -43,16 +32,8 @@ class EventRegistry:
     def register(self, name: str, cb: Callable):
         self._subs[name].append(cb)
 
-    def emit(self, name: str, *args, **kw):
+    def emit(self, name: str, *args):
         self.emitted[name] += 1
-        if kw or not self.batched:
-            for cb in list(self._subs[name]):
-                # event dispatch is in-process: effectively immediate
-                if kw:
-                    self.sim.after(0.0, (lambda c=cb: c(*args, **kw)), note=name)
-                else:
-                    self.sim.after(0.0, cb, note=name, args=args)
-            return
         subs = self._subs[name]
         if not subs:
             return

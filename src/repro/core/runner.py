@@ -86,8 +86,6 @@ class ControlPlane:
                  sample_mode: str = "full",
                  usage_mode: str = "sampled",
                  retain_pod_log: bool = True,
-                 lifecycle: Optional[str] = None,
-                 queue: Optional[str] = None,
                  fold_completed: bool = False,
                  capture_trace: bool = True,
                  chaos: Optional[ChaosSchedule] = None,
@@ -109,11 +107,11 @@ class ControlPlane:
         self.engine_name = engine_name
         self.params = params
         self.sample_resources = sample_resources
-        self.sim = Sim(queue=queue)
+        self.sim = Sim()
         self.cluster = Cluster(self.sim, params, cluster_cfg,
                                payload_mode=payload_mode, seed=seed,
                                retain_pod_log=retain_pod_log,
-                               lifecycle=lifecycle, placement=placement)
+                               placement=placement)
         self.volumes = VolumeManager(self.sim, self.cluster, params)
         self.metrics = MetricsCollector(self.sim, self.cluster, params,
                                         sample_mode=sample_mode,
@@ -134,8 +132,7 @@ class ControlPlane:
 
         if engine_name == "kubeadaptor":
             self.informers = InformerSet(self.sim, self.cluster, params)
-            self.events = EventRegistry(self.sim,
-                                        batched=self.cluster.lifecycle == "fast")
+            self.events = EventRegistry(self.sim)
             self.arbiter = AdmissionArbiter(
                 self.informers, policy=admission_policy,
                 on_defer=self.metrics.note_admission_deferred,

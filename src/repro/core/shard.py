@@ -126,8 +126,6 @@ class ShardSpec:
     sample_mode: str = "full"
     usage_mode: str = "sampled"
     retain_pod_log: bool = True
-    lifecycle: Optional[str] = None
-    queue: Optional[str] = None
     fold_completed: bool = False
     capture_trace: bool = True
     streams: List[dict] = field(default_factory=list)
@@ -168,8 +166,8 @@ def _build_shard_plane(spec: ShardSpec) -> ControlPlane:
         admission_policy=spec.admission_policy,
         sample_resources=spec.sample_resources,
         sample_mode=spec.sample_mode, usage_mode=spec.usage_mode,
-        retain_pod_log=spec.retain_pod_log, lifecycle=spec.lifecycle,
-        queue=spec.queue, fold_completed=spec.fold_completed,
+        retain_pod_log=spec.retain_pod_log,
+        fold_completed=spec.fold_completed,
         capture_trace=spec.capture_trace, chaos=spec.chaos,
         placement=spec.placement, deschedule=spec.deschedule,
         autoscale=spec.autoscale, gateway=spec.gateway,
@@ -248,9 +246,7 @@ def _run_shard(spec: ShardSpec, die_at: Optional[float] = None) -> dict:
         "api_calls": res.cluster.api_calls,
         "informer_copies": _cluster_mod.SNAPSHOTS_MADE - copies0,
         "peak_pending_pods": getattr(res.cluster, "max_pending_pods", 0),
-        "queue": res.sim.queue_name,
         "usage_mode": res.metrics.usage_mode,
-        "lifecycle": getattr(res.cluster, "lifecycle", "chained"),
         "completed_workflows": partial.completed,
         "failed_workflows": partial.failed,
         "arbiter": (res.arbiter.counters()
@@ -609,8 +605,6 @@ class ShardedControlPlane:
                  sample_mode: str = "full",
                  usage_mode: str = "sampled",
                  retain_pod_log: bool = True,
-                 lifecycle: Optional[str] = None,
-                 queue: Optional[str] = None,
                  fold_completed: bool = False,
                  capture_trace: bool = True,
                  processes: bool = True,
@@ -661,7 +655,6 @@ class ShardedControlPlane:
             admission_policy=admission_policy,
             sample_resources=sample_resources, sample_mode=sample_mode,
             usage_mode=usage_mode, retain_pod_log=retain_pod_log,
-            lifecycle=lifecycle, queue=queue,
             fold_completed=fold_completed, capture_trace=capture_trace,
             record_bindings=record_bindings, profile=profile,
             chaos=chaos.spawn(i) if chaos is not None else None,

@@ -85,7 +85,7 @@ def _run_single(chaos, seed=7, policy="fair-share", params=None,
         params=params or cal.DEFAULT_PARAMS,
         cluster_cfg=cal.PaperCluster(n_nodes=n_nodes),
         sample_mode="streaming", usage_mode="event",
-        retain_pod_log=False, lifecycle="fast", chaos=chaos)
+        retain_pod_log=False, chaos=chaos)
     bindings = []
     inner = plane.cluster._bind
 
@@ -240,7 +240,7 @@ def test_evict_during_teardown_does_not_requeue():
         "kubeadaptor", admission_policy="fair-share", seed=7,
         cluster_cfg=cal.PaperCluster(n_nodes=6),
         sample_mode="streaming", usage_mode="event",
-        retain_pod_log=False, lifecycle="fast")
+        retain_pod_log=False)
     plane.add_stream(MONTAGE, repeats=3, tenant="prod",
                      arrival="concurrent", concurrency=3)
     plane.gateway.start()
@@ -286,7 +286,7 @@ def _sharded(processes, chaos=None, params=None, **kw):
         params=params or cal.DEFAULT_PARAMS,
         cluster_cfg=cal.PaperCluster(n_nodes=8),
         sample_mode="streaming", usage_mode="event", retain_pod_log=False,
-        lifecycle="fast", processes=processes, chaos=chaos,
+        processes=processes, chaos=chaos,
         heartbeat_s=0.2, **kw)
     # tenant names chosen to span both shards under the crc32 partition:
     # batch-a/alpha -> shard 0, prod-a/gamma -> shard 1

@@ -203,7 +203,7 @@ def _run_single(gateway=None, wal_path=None, chaos=None, seed=7,
         "kubeadaptor", admission_policy=policy, seed=seed,
         cluster_cfg=cal.PaperCluster(n_nodes=n_nodes),
         sample_mode="streaming", usage_mode="event",
-        retain_pod_log=False, lifecycle="fast", chaos=chaos,
+        retain_pod_log=False, chaos=chaos,
         gateway=gateway, wal_path=wal_path)
     bindings = []
     inner = plane.cluster._bind
@@ -423,7 +423,7 @@ def _sharded(processes, policy="fair-share", wal_dir=None, **kw):
         2, admission_policy=policy, seed=42,
         cluster_cfg=cal.PaperCluster(n_nodes=8),
         sample_mode="streaming", usage_mode="event", retain_pod_log=False,
-        lifecycle="fast", processes=processes, heartbeat_s=0.2,
+        processes=processes, heartbeat_s=0.2,
         gateway=GATE, wal_dir=wal_dir, **kw)
     # tenant names span both shards under the crc32 partition:
     # batch-a/alpha -> shard 0, prod-a/gamma -> shard 1
@@ -532,7 +532,7 @@ def test_trace_v2_records_gateway_events_and_v1_still_loads(tmp_path):
         "kubeadaptor", admission_policy="fair-share", seed=7,
         cluster_cfg=cal.PaperCluster(n_nodes=8),
         sample_mode="streaming", usage_mode="event",
-        retain_pod_log=False, lifecycle="fast", gateway=pol)
+        retain_pod_log=False, gateway=pol)
     plane.add_stream(MONTAGE, repeats=8, tenant="prod",
                      arrival="concurrent", concurrency=4)
     res = plane.run()

@@ -33,6 +33,9 @@ from repro.core.stats import StreamingStat
 PINNED = {
     "paper": ("3832b6fec9f1d4afd55898e04dba44377eb37258b3fb3b19c94f9a994f70a3ca", 42),
     "multi": ("546262a798da1d30d32312751fd6aa026f80e335a1e6b0fb56d33d9ef66f1834", 70),
+    # recorded on the commit that still had the one-event-per-hop pod
+    # lifecycle and the calendar queue (all four combinations agreed)
+    "stress": ("721c97cb371d9f9d093262afe18c68e86edc5e0eee28c24b822ee0f16182412a", 84),
 }
 
 
@@ -73,9 +76,28 @@ def _multi_scenario():
     return _binding_sequence(plane, load)
 
 
+def _stress_plane():
+    """The event core's stress scenario: fair-share, montage x2 with
+    concurrency 2 plus a 12-wide fan-out Poisson stream, 3 nodes."""
+    plane = ControlPlane("kubeadaptor", admission_policy="fair-share",
+                         cluster_cfg=cal.PaperCluster(n_nodes=3), seed=11)
+    mont = make_workflow("montage", get_workflow_spec("montage"))
+    fan = make_workflow("fan", wide_fanout(width=12))
+    plane.add_stream(mont, repeats=2, tenant="a", arrival="concurrent",
+                     concurrency=2, weight=2.0)
+    plane.add_stream(fan, repeats=3, tenant="b", arrival="poisson",
+                     rate=0.2, burst=2, weight=1.0)
+    return plane
+
+
+def _stress_scenario():
+    return _binding_sequence(_stress_plane(), lambda p: None)
+
+
 @pytest.mark.parametrize("name,scenario",
                          [("paper", _paper_scenario),
-                          ("multi", _multi_scenario)])
+                          ("multi", _multi_scenario),
+                          ("stress", _stress_scenario)])
 def test_binding_sequence_pinned(name, scenario):
     """Fixed seed => the exact pre-refactor pod->node binding order."""
     seq = scenario()
