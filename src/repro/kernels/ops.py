@@ -3,7 +3,7 @@
 Backends (callers name one; nothing is picked behind their back):
   'pallas'     — native TPU lowering (production target)
   'interpret'  — Pallas interpret mode (kernel body on CPU; validation)
-  'jnp'        — the pure-jnp production paths (models/attention,
+  'jnp'        — the pure-jnp paths (models/attention,
                  models/ssm), used by the distributed dry-run
 """
 from __future__ import annotations

@@ -15,9 +15,13 @@ is cheaper than materializing a repeated cache.
 The chunked path is the memory-subquadratic attention used for long
 prefill and training (32k prefill, DeepSeek-V2's 8k train step):
 causal query blocks against the key chunks up to their own, one block's
-O(chunk^2) scores live at a time instead of O(S^2). The Pallas flash
-kernel (kernels/flash_attention.py) implements the same algorithm for
-TPU; ``kernels/ops.py`` dispatches between them.
+O(chunk^2) scores live at a time instead of O(S^2). On the TPU,
+``causal_attention`` runs causal self-attention of that length as the
+splash-attention Pallas kernel (kernels/splash.py: the same algorithm,
+its score tiles in VMEM, with its own backward); the chunked scan is
+the path of every other platform and the reference. The Pallas flash
+kernel (kernels/flash_attention.py, forward only) is what
+``kernels/ops.py`` names ``pallas``.
 """
 from __future__ import annotations
 
@@ -27,6 +31,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import splash
 from repro.models.layers import RunConfig, apply_rope, dense_init
 
 NEG_INF = -1e30
@@ -155,6 +160,21 @@ def chunked_attention(q, k, v, *, chunk: int, causal: bool = True,
     return jnp.concatenate(outs, axis=2).transpose(0, 2, 1, 3)
 
 
+def causal_attention(q, k, v, *, chunk: int, scale=None):
+    """Causal self-attention, q (B,S,H,dq), k (B,S,H,dq), v (B,S,H,dv);
+    ``scale`` defaults to dq ** -0.5. Lowered for the TPU, with S a
+    whole number of the kernel's blocks, the splash kernel; otherwise,
+    and on every other platform, ``chunked_attention`` over ``chunk``
+    keys. The platform is the lowering's, the rest the shapes'."""
+    jnp_path = partial(chunked_attention, chunk=chunk, causal=True,
+                       scale=scale)
+    if not splash.supports(q.shape[1], k.shape[1]):
+        return jnp_path(q, k, v)
+    kernel = partial(splash.causal_attention,
+                     scale=q.shape[-1] ** -0.5 if scale is None else scale)
+    return jax.lax.platform_dependent(q, k, v, tpu=kernel, default=jnp_path)
+
+
 def _gqa_fold(q, n_kv):
     """(B,S,H,hd) -> (B,S,K,G,hd)."""
     B, S, H, hd = q.shape
@@ -242,8 +262,7 @@ def apply_attention(
         vf = rc.constrain(repeat_kv(v, H), kv_axes)
         S = x.shape[1]
         if causal and S > rc.attn_dense_max:
-            out = chunked_attention(q, kf, vf, chunk=rc.attn_chunk or 1024,
-                                    causal=True)
+            out = causal_attention(q, kf, vf, chunk=rc.attn_chunk or 1024)
         else:
             out = full_attention(q, kf, vf, causal=causal)
         out = rc.constrain(out, q_axes)

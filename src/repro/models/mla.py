@@ -12,8 +12,9 @@ Per position:
 
 Train and prefill expand k_nope and v per head (and broadcast the shared
 k_pe over heads) and run the model's attention (``attention.py``: dense,
-or above ``rc.attn_dense_max`` positions causal query blocks over key
-chunks). Decode is *absorbed*: the cache holds only c and the rotated
+or above ``rc.attn_dense_max`` positions ``causal_attention``: the
+splash kernel on the TPU, causal query blocks over key chunks
+elsewhere). Decode is *absorbed*: the cache holds only c and the rotated
 k_pe (kv_lora_rank + qk_rope_head_dim values a position), the query is
 folded through W_kvb's key half and the latent output through its value
 half, so no per-head key or value is formed over the cache.
@@ -103,9 +104,9 @@ def apply_mla(params, x, cfg, rc: RunConfig, positions, *, cache=None,
             k_pe[:, :, None], (B, S, H, k_pe.shape[-1]))], -1)
         q = jnp.concatenate([qn, qp], -1)
         if S > rc.attn_dense_max:
-            o = attn_lib.chunked_attention(q, k, kv[..., dn:],
-                                           chunk=rc.attn_chunk or 1024,
-                                           scale=scale)
+            o = attn_lib.causal_attention(q, k, kv[..., dn:],
+                                          chunk=rc.attn_chunk or 1024,
+                                          scale=scale)
         else:
             o = attn_lib.full_attention(q, k, kv[..., dn:], causal=True,
                                         scale=scale)

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import ref, splash
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
@@ -97,6 +97,48 @@ def test_flash_kernel_matches_production_chunked():
     b_ = chunked_attention(q, k, v, chunk=64, causal=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-5,
                                rtol=2e-5)
+
+
+@pytest.mark.parametrize("path,S", [("kernel", 256),    # one 256-row block
+                                    ("kernel", 384),    # three of 128
+                                    ("cpu_default", 256),
+                                    ("unsupported", 192)])
+def test_causal_attention_matches_chunked_at_mla_widths(path, S):
+    """The splash kernel (interpret mode) against the chunked scan at
+    MLA's q/k width 192, v width 128 and a non-unit scale: the output
+    and dq, dk, dv under ``jax.checkpoint``, to bf16 rounding (q is
+    scaled before its cast). ``causal_attention`` itself lowered for the
+    CPU, and a length the kernel cannot take (not a whole number of 128
+    rows), is the chunked scan, bit for bit."""
+    from repro.models.attention import causal_attention, chunked_attention
+    ks = jax.random.split(jax.random.PRNGKey(S), 4)
+    B, H, scale = 1, 2, 0.115
+    q, k = (jax.random.normal(kk, (B, S, H, 192), jnp.bfloat16)
+            for kk in ks[:2])
+    v = jax.random.normal(ks[2], (B, S, H, 128), jnp.bfloat16)
+    cot = jax.random.normal(ks[3], (B, S, H, 128), jnp.float32)
+    chunk = 64
+
+    def run(attend):
+        f = lambda q, k, v: jnp.sum(  # noqa: E731
+            cot * jax.checkpoint(attend)(q, k, v).astype(jnp.float32))
+        out = jax.jit(jax.checkpoint(attend))(q, k, v)
+        return [np.asarray(a, np.float32) for a in
+                (out, *jax.jit(jax.grad(f, (0, 1, 2)))(q, k, v))]
+    want = run(lambda q, k, v: chunked_attention(q, k, v, chunk=chunk,
+                                                 scale=scale))
+    if path == "kernel":
+        assert splash.supports(S, S)
+        got = run(lambda q, k, v: splash.causal_attention(
+            q, k, v, scale=scale, interpret=True))
+        for a, b in zip(got, want):
+            assert np.abs(a - b).max() <= 2e-2 * np.abs(b).max()
+        return
+    assert splash.supports(S, S) == (path == "cpu_default")
+    got = run(lambda q, k, v: causal_attention(q, k, v, chunk=chunk,
+                                               scale=scale))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_ops_dispatch():

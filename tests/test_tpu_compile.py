@@ -110,7 +110,10 @@ def test_train_step_fits_one_chip_at_smoke_size(one_chip, B, accum):
         cfg, None, B=B, S=SMOKE.TRAIN_S,
         rc=RunConfig(remat=True, remat_policy="full"),
         trc=TrainRunConfig(grad_accum=accum))
-    _fits(step.lower(_on(one_chip, state), _on(one_chip, batch)).compile())
+    compiled = step.lower(_on(one_chip, state), _on(one_chip, batch)).compile()
+    _fits(compiled)
+    # below attn_dense_max qwen2 attends densely: no attention kernel
+    assert "splash_mha" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("stage", ["prefill", "decode"])
@@ -188,3 +191,28 @@ def test_decode_casts_weights_inside_the_layer_scan(one_chip, arch, B, cache):
     weights |= {params[k].shape for k in ("embed", "head") if k in params}
     cast = _entry_bf16_casts(compiled.as_text())
     assert len(weights) >= 5 and not weights & cast, sorted(weights & cast)
+
+
+def test_mla_layer_attention_lowers_to_the_splash_kernel(one_chip):
+    """One DeepSeek-V2-Lite MLA layer, forward and backward under full
+    remat, at dsv2lite.train8k's B2 S8192: attention is the splash
+    forward, dq and dkv kernels, no key-chunk loop is left, and it
+    fits."""
+    from repro.models import mla as mla_lib
+    cfg, rc = _bench_mla_moe()
+    params = jax.eval_shape(
+        lambda: mla_lib.init_mla(jax.random.PRNGKey(0), cfg, jnp.bfloat16))
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    @jax.checkpoint
+    def layer(p, x):
+        return mla_lib.apply_mla(p, x, cfg, rc, jnp.arange(x.shape[1]))[0]
+    compiled = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)), (0, 1))
+    ).lower(_on(one_chip, params), x).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    for phase in ("fwd", "dq", "dkv"):
+        assert f"splash_mha_{phase}" in text, phase
+    assert " while(" not in text
